@@ -119,6 +119,9 @@ def _run_probe(writer_dir: Path, mmap: bool) -> dict:
     src = str(Path(__file__).resolve().parent.parent / "src")
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if "PYTHONPATH" in env else "")
+    # the probe serves on the host (device=False); keeping it off the
+    # accelerator leaves the chip to the parent process, which holds it
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, str(Path(__file__).resolve()), "--probe-dir",
          str(writer_dir)] + (["--probe-mmap"] if mmap else []),

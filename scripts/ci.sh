@@ -17,11 +17,13 @@ export REPRO_DIFF_SEED
 # run them in parallel without dropping a single test: pytest-xdist when the
 # environment has it, otherwise a shell-level fan-out over disjoint file
 # buckets (size-ordered round-robin as a duration proxy; the differential
-# suite gets a bucket of its own).
+# suite gets a bucket of its own).  The tests run on the CPU (Pallas kernels
+# in interpret mode): on a machine with a TPU, parallel workers would
+# otherwise compete for the one chip.
 PYTEST_BUCKETS=${PYTEST_BUCKETS:-4}
 if python -c "import xdist" 2> /dev/null; then
     echo "== tier-1 + differential: pytest -n auto (xdist, seed $REPRO_DIFF_SEED) =="
-    python -m pytest -q -n auto
+    JAX_PLATFORMS=cpu python -m pytest -q -n auto
 else
     echo "== tier-1 + differential: $PYTEST_BUCKETS+1 parallel pytest buckets (seed $REPRO_DIFF_SEED) =="
     BUCKET_DIR=$(mktemp -d)
@@ -37,7 +39,7 @@ else
     b=0
     while [ "$b" -le "$PYTEST_BUCKETS" ]; do
         # shellcheck disable=SC2046
-        python -m pytest -q --basetemp="$BUCKET_DIR/tmp$b" \
+        JAX_PLATFORMS=cpu python -m pytest -q --basetemp="$BUCKET_DIR/tmp$b" \
             $(tr '\n' ' ' < "$BUCKET_DIR/bucket$b.lst") \
             > "$BUCKET_DIR/bucket$b.log" 2>&1 &
         pids="$pids $!"

@@ -10,8 +10,8 @@ cd "$(dirname "$0")/.."
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 export PYTHONPATH
 
-echo "== tier-1: pytest =="
-python -m pytest -x -q "$@"
+echo "== tier-1: pytest (CPU; Pallas kernels in interpret mode) =="
+JAX_PLATFORMS=cpu python -m pytest -x -q "$@"
 
 echo "== backend registry =="
 python scripts/list_backends.py

@@ -4,7 +4,7 @@ The ragged part — gathering each entry's prefix-summed d-gap slice from
 the shared pool — happens on the XLA side (a contiguous gather); these
 ops take the rectangular (R, L) prefix-sum tile, pad it to the kernel
 grid, run the fused Pallas kernel and trim.  L is the collection's
-``max_phrase`` bound, padded to the 128-lane boundary inside.
+``max_phrase`` bound, padded to the kernel's lane tiling inside.
 """
 
 from __future__ import annotations
@@ -12,13 +12,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .kernel import LANE, RBLK, decode_rows_2d, probe_rows_2d
+from ..platform import padded_lanes
+from .kernel import LBLK, RBLK, decode_rows_2d, probe_rows_2d
 
 
 def _pad2(gaps: jax.Array, base: jax.Array, lens: jax.Array):
     r, l = gaps.shape
     rpad = (-r) % RBLK
-    lpad = (-l) % LANE
+    lpad = padded_lanes(l, LBLK) - l
     g = jnp.pad(gaps.astype(jnp.int32), ((0, rpad), (0, lpad)))
     b = jnp.pad(base.astype(jnp.int32), (0, rpad)).reshape(-1, 1)
     n = jnp.pad(lens.astype(jnp.int32), (0, rpad)).reshape(-1, 1)

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import _DEAD, _SIGN, LANE, RBLK, minhash_rows_2d
+from ..platform import interpret_mode, padded_lanes
+from .kernel import _DEAD, _SIGN, LBLK, RBLK, minhash_rows_2d
 from .ref import minhash_rows_ref
 
 
@@ -75,13 +76,11 @@ def minhash_signatures(shingles: np.ndarray, lens: np.ndarray,
         ab = jnp.asarray(np.stack([a32, b32], axis=1))
         out = _minhash_jnp(s32, ln, ab)
     elif backend == "kernel":
-        dpad, lpad = (-d) % RBLK, (-l) % LANE
+        dpad, lpad = (-d) % RBLK, padded_lanes(l, LBLK) - l
         s_p = jnp.pad(s32, ((0, dpad), (0, lpad)))
         ln_p = jnp.pad(ln, ((0, dpad), (0, 0)))
-        a_p = jnp.asarray(a32).reshape(-1, 1)
-        b_p = jnp.asarray(b32).reshape(-1, 1)
-        out = minhash_rows_2d(s_p, ln_p, a_p, b_p,
-                              interpret=jax.default_backend() != "tpu")[:d]
+        out = minhash_rows_2d(s_p, ln_p, jnp.asarray(a32), jnp.asarray(b32),
+                              interpret=interpret_mode())[:d]
     else:
         raise ValueError(f"unknown minhash backend {backend!r}; "
                          f"use 'auto', 'kernel', 'jnp', or 'ref'")

@@ -6,7 +6,8 @@ jax device state.
 
 from __future__ import annotations
 
-from ..sharding.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -28,6 +28,7 @@ least-loaded dispatch.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import numpy as np
@@ -39,6 +40,7 @@ from ..core.writer import IndexWriter
 from ..data import generate_collection
 from ..data.queries import sample_traffic
 from ..serving.session import Session
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -92,6 +94,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.ingest and not (args.index_dir or args.save_dir):
         ap.error("--ingest needs a live directory (--index-dir or --save-dir)")
+    print(f"compile cache: {enable_compile_cache()}")
 
     spec = get_backend_spec(args.store)
     print(f"backend {spec.name}: family={spec.family} "
@@ -198,6 +201,7 @@ def main() -> None:
     agree = sum(1 for h, d in zip(host_results, results)
                 if np.array_equal(np.asarray(h), np.asarray(d)))
     print(f"host/planned agreement: {agree}/{args.queries} queries")
+    disagreements = args.queries - agree
 
     if args.frontend:
         import asyncio
@@ -242,6 +246,7 @@ def main() -> None:
             1 for h, d in zip(host_results, fe_results)
             if d is not None and np.array_equal(np.asarray(h), np.asarray(d)))
         print(f"host/frontend agreement: {fe_agree}/{args.queries} queries")
+        disagreements += args.queries - fe_agree
         asyncio.run(fe.close())
 
     if args.ingest:
@@ -268,6 +273,8 @@ def main() -> None:
               f"{after['plans_compiled'] - before['plans_compiled']} re-plans "
               f"(segment shape changed), total segments "
               f"{after.get('segments', 1)}")
+    if disagreements:
+        sys.exit(f"FAIL: {disagreements} answer(s) differ from the host path")
 
 
 if __name__ == "__main__":

@@ -400,7 +400,8 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
     before it.  ``probe="kernel"`` routes the inner membership probes
     through the Pallas kernels (interpret mode off-TPU):
     ``anchor_intersect`` tiled compares for the dense layout, plus
-    ``fused_decode`` expansion for the fused one.
+    ``fused_decode`` expansion for the fused one (compiled on the TPU,
+    interpreted on the CPU; see ``kernels.platform.interpret_mode``).
 
     ``layout`` selects the device memory model: "dense" reads the
     ``(n_c, expand_len)`` expand tables; "fused" keeps only the compressed
@@ -409,10 +410,12 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
     """
     phrase = mode == PHRASE
     fused = layout == "fused"
-    interpret = jax.default_backend() != "tpu"
     member = None
     decode = None
     if probe == "kernel":
+        from ..kernels.platform import interpret_mode
+
+        interpret = interpret_mode()
         if fused:
             from ..kernels.fused_decode.ops import decode_rows
 
@@ -437,7 +440,9 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
             ds = index.get("doc_starts")
             doc = vals if ds is None else jnp.searchsorted(ds, vals, side="right") - 1
             doc = jnp.where(match, doc, -1)
-            prev = jax.lax.associative_scan(jnp.maximum, doc, axis=1)
+            # cummax, not associative_scan: over a (16, 2^20) window the TPU
+            # compiler finishes cummax in seconds and the scan not in 5 min
+            prev = jax.lax.cummax(doc, axis=1)
             prev = jnp.concatenate(
                 [jnp.full((doc.shape[0], 1), -1, doc.dtype), prev[:, :-1]], axis=1)
             return doc, match & (doc > prev)

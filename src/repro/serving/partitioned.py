@@ -29,10 +29,10 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core.anchors import AnchoredIndex, build_anchored
-from ..sharding.compat import shard_map
 from .engine import MAX_CAND_ROWS, _probe_terms, candidates_for, encode_queries
 
 
@@ -45,9 +45,13 @@ class PartitionedAnchoredIndex:
 
     @classmethod
     def build(cls, lists: list[np.ndarray], n_docs: int, n_shards: int,
-              bounds: np.ndarray | None = None, **kw) -> "PartitionedAnchoredIndex":
+              bounds: np.ndarray | None = None, mesh=None,
+              shard_axis: str = "data", **kw) -> "PartitionedAnchoredIndex":
         """``bounds`` overrides the equal-width split — pass document-start
-        positions for a positional index so phrases never span shards."""
+        positions for a positional index so phrases never span shards.
+        With a ``mesh`` each shard's slice is placed once on its own
+        device along ``shard_axis`` (otherwise everything lands on the
+        default device)."""
         if bounds is None:
             bounds = np.linspace(0, n_docs, n_shards + 1).astype(np.int64)
         else:
@@ -73,19 +77,25 @@ class PartitionedAnchoredIndex:
             x = np.asarray(x)
             return np.pad(x, ((0, n - x.shape[0]), (0, w - x.shape[1])), constant_values=fill)
 
-        arrays = {
-            "anchors": jnp.asarray(np.stack([
-                pad1(a.anchors, max_nc, fill=2**31 - 1) for a in shards]), jnp.int32),
-            "c_offsets": jnp.asarray(np.stack([
-                pad1(a.c_offsets, n_terms + 1, fill=int(a.c_offsets[-1])) for a in shards]), jnp.int32),
-            "expand": jnp.asarray(np.stack([
-                pad2(a.expand, max_nc, el) for a in shards]), jnp.int32),
-            "expand_valid": jnp.asarray(np.stack([
-                pad2(a.expand_valid, max_nc, el) for a in shards])),
-            "lengths": jnp.asarray(np.stack([
-                pad1(a.lengths, n_terms) for a in shards]), jnp.int32),
-            "doc_base": jnp.asarray(bounds[:-1], jnp.int32),
+        host = {
+            "anchors": np.stack([
+                pad1(a.anchors, max_nc, fill=2**31 - 1) for a in shards]).astype(np.int32),
+            "c_offsets": np.stack([
+                pad1(a.c_offsets, n_terms + 1, fill=int(a.c_offsets[-1])) for a in shards]).astype(np.int32),
+            "expand": np.stack([
+                pad2(a.expand, max_nc, el) for a in shards]).astype(np.int32),
+            "expand_valid": np.stack([
+                pad2(a.expand_valid, max_nc, el) for a in shards]).astype(bool),
+            "lengths": np.stack([
+                pad1(a.lengths, n_terms) for a in shards]).astype(np.int32),
+            "doc_base": np.asarray(bounds[:-1], np.int32),
         }
+        if mesh is None:
+            arrays = {k: jnp.asarray(v) for k, v in host.items()}
+        else:
+            arrays = {k: jax.device_put(v, NamedSharding(
+                mesh, P(shard_axis, *([None] * (v.ndim - 1)))))
+                for k, v in host.items()}
         return cls(arrays=arrays, doc_bounds=bounds, n_shards=n_shards, expand_len=el)
 
     @classmethod
@@ -145,7 +155,8 @@ def make_partitioned_serve_step(max_terms: int, mesh, shard_axis: str = "data",
                                   row_start=row_start)
         return vals[None], mask[None]
 
-    mapped = shard_map(local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs)
 
     def serve(arrays, qt, ql, row_start=0):
         return mapped(arrays, qt, ql, jnp.asarray(row_start, jnp.int32))
@@ -215,7 +226,8 @@ class PartitionedServer:
         """Shard an already-built index (any registered backend) into the
         partitioned layout — the in-memory counterpart of :meth:`open`,
         used by the replicated serving tier to stamp out shard sets."""
-        pidx = PartitionedAnchoredIndex.from_index(index, n_shards=n_shards, **kw)
+        pidx = PartitionedAnchoredIndex.from_index(
+            index, n_shards=n_shards, mesh=mesh, shard_axis=shard_axis, **kw)
         return cls(pidx=pidx, host_index=index, mesh=mesh, shard_axis=shard_axis)
 
     @classmethod

@@ -17,7 +17,8 @@ from functools import partial
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from repro.configs import get_config
-from repro.sharding.compat import AxisType, make_mesh, shard_map
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.models import steps as steps_mod
 from repro.sharding.specs import param_specs_for, input_specs_sharding_for, opt_state_specs
 from repro.train.optimizer import OptConfig

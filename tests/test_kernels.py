@@ -39,10 +39,12 @@ def test_dgap_decode_empty_and_single():
                            jnp.asarray([6], jnp.int32))
 
 
-@pytest.mark.parametrize("r,l", [(0, 8), (1, 1), (3, 41), (255, 127), (256, 128), (257, 129)])
+@pytest.mark.parametrize("r,l", [(0, 8), (1, 1), (3, 41), (255, 127), (256, 128), (257, 129),
+                                 (3, 1500), (2, 2100)])
 def test_fused_decode_rows(r, l):
     """Fused decode kernel vs the NumPy oracle across the RBLK/LANE tile
-    boundaries (256 rows x 128 lanes) ± 1."""
+    boundaries (256 rows x 128 lanes) ± 1, and rows wider than one lane
+    tile (LBLK = 1024)."""
     from repro.kernels.fused_decode.ops import decode_rows
     from repro.kernels.fused_decode.ref import decode_rows_ref
 
@@ -56,7 +58,7 @@ def test_fused_decode_rows(r, l):
     assert np.array_equal(np.asarray(vals)[rvalid], rvals[rvalid])
 
 
-@pytest.mark.parametrize("r,l", [(0, 8), (3, 41), (257, 129)])
+@pytest.mark.parametrize("r,l", [(0, 8), (3, 41), (257, 129), (6, 2100)])
 def test_fused_probe_rows(r, l):
     """Fused decode+membership kernel vs the NumPy oracle: hits on real
     row values, misses on values never decoded."""
@@ -73,6 +75,21 @@ def test_fused_probe_rows(r, l):
     got = probe_rows(jnp.asarray(gaps), jnp.asarray(base), jnp.asarray(lens),
                      jnp.asarray(targets), interpret=True)
     assert np.array_equal(np.asarray(got), probe_rows_ref(gaps, base, lens, targets))
+
+
+@pytest.mark.parametrize("d,l", [(1, 1), (65, 130), (3, 2049)])
+def test_minhash_rows_kernel(d, l):
+    """MinHash kernel vs the NumPy oracle across the row block (64), the
+    lane boundary (128) and the lane tile (LBLK = 2048), with empty rows."""
+    from repro.kernels.minhash_sig.ops import hash_params, minhash_signatures
+    from repro.kernels.minhash_sig.ref import minhash_rows_ref
+
+    shingles = rng.integers(0, 2**32, size=(d, l), dtype=np.uint32)
+    lens = rng.integers(0, l + 1, size=d)
+    lens[0] = 0
+    a, b = hash_params(64, seed=3)
+    got = minhash_signatures(shingles, lens, a, b, backend="kernel")
+    assert np.array_equal(got, minhash_rows_ref(shingles, lens, a, b))
 
 
 @pytest.mark.parametrize("nq,na", [(1, 1), (7, 100), (300, 5000), (1024, 2048)])

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.checkpointer import Checkpointer
-from repro.sharding.compat import AxisType, make_mesh, shard_map
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType
 from repro.train.loop import TrainLoop, WatchdogStats
 from repro.train.optimizer import OptConfig, opt_init, opt_update, schedule
 
