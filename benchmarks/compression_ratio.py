@@ -6,9 +6,7 @@ regressions between PRs were anecdotal.  This benchmark builds one
 backend per family over the same repetitive collection at two edit
 rates (highly repetitive and loosely repetitive) and reports each
 store's ``space_fraction`` plus build time, with a JSON object on the
-last stdout line for ``scripts/record_bench.py`` ->
-``BENCH_compression.json`` — every CI run appends its ratios next to
-its predecessors'.
+last stdout line.
 
     PYTHONPATH=src python benchmarks/compression_ratio.py
     PYTHONPATH=src python benchmarks/compression_ratio.py --stores vbyte rlcsa

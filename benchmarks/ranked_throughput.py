@@ -16,8 +16,8 @@ byte-identical rankings (asserted per query, not sampled):
   and zero retraces (rank steps are cached per (width, k) like every
   other kind).
 
-Emits a JSON object (one entry per batch size) on the last stdout line
-for ``scripts/record_bench.py`` -> ``BENCH_serving.json``.
+Emits a JSON object (one entry per batch size) on the last stdout
+line.
 
     PYTHONPATH=src python benchmarks/ranked_throughput.py
     PYTHONPATH=src python benchmarks/ranked_throughput.py --store rlcsa --k 5

@@ -19,8 +19,7 @@ the collection is never materialized):
   during and after the swap must be byte-identical to the quiesced
   answers (checked, not assumed).
 
-Emits a JSON object on stdout after the human-readable report (the
-``record_bench.py`` contract).
+Emits a JSON object on stdout after the human-readable report.
 
     PYTHONPATH=src python benchmarks/scale_open.py            # full scale
     PYTHONPATH=src python benchmarks/scale_open.py --smoke    # CI-sized

@@ -11,9 +11,7 @@ load: p50/p95/p99/mean latency, achieved q/s, **reject rate** (typed
 queue-full rejections, never hangs), and **result-cache hit rate** (the
 traffic is drawn from a finite query pool, like real serving traffic).
 
-Emits a JSON object on stdout after the human-readable table —
-``scripts/ci.sh`` appends it to the checked-in ``BENCH_serving.json``
-trajectory so tail-latency regressions are visible across PRs.
+Emits a JSON object on stdout after the human-readable table.
 
     PYTHONPATH=src python benchmarks/serving_latency.py
     PYTHONPATH=src python benchmarks/serving_latency.py --store vbyte --queries 150

@@ -227,7 +227,8 @@ def member_batch(idx: AnchoredIndex, list_ids: jax.Array, values: jax.Array) -> 
             new_h = jnp.where(active & ~go_right, mid, h)
             return (new_l, new_h)
 
-        l, _ = jax.lax.fori_loop(0, 32, body, (lid_lo, lid_hi))
+        with jax.named_scope("anchor_search"):
+            l, _ = jax.lax.fori_loop(0, 32, body, (lid_lo, lid_hi))
         j = jnp.maximum(l - 1, lid_lo)
         row = idx.expand[j]
         ok = idx.expand_valid[j] & (row == t)
@@ -263,7 +264,8 @@ def member_batch_compressed(
             new_h = jnp.where(active & ~go_right, mid, h)
             return (new_l, new_h)
 
-        l, _ = jax.lax.fori_loop(0, 32, body, (lid_lo, lid_hi))
+        with jax.named_scope("anchor_search"):
+            l, _ = jax.lax.fori_loop(0, 32, body, (lid_lo, lid_hi))
         j = jnp.maximum(l - 1, lid_lo)
         # membership of t in entry j == membership of t - anchors[j] in its
         # sorted prefix-sum row [c_ptr[j], c_ptr[j] + c_len[j])
@@ -280,7 +282,8 @@ def member_batch_compressed(
             new_h = jnp.where(active & ~go_right, mid, h2)
             return (new_l, new_h)
 
-        l2, _ = jax.lax.fori_loop(0, depth, body2, (p_lo, p_hi))
+        with jax.named_scope("pool_search"):
+            l2, _ = jax.lax.fori_loop(0, depth, body2, (p_lo, p_hi))
         hit = (l2 < p_hi) & (idx.pool[jnp.minimum(l2, pool_top)] == tt)
         return hit & (lid_lo < lid_hi)
 

@@ -87,6 +87,7 @@ def anchor_probe_sliced_2d(queries: jax.Array, lo: jax.Array, hi: jax.Array,
     qspec = pl.BlockSpec((QBLK, 1), lambda qi, ai: (qi, 0))
     return pl.pallas_call(
         _probe_slice_kernel,
+        name="anchor_probe_sliced",
         grid=grid,
         in_specs=[qspec, qspec, qspec,
                   pl.BlockSpec((1, ABLK), lambda qi, ai: (0, ai))],
@@ -106,6 +107,7 @@ def anchor_probe_2d(queries: jax.Array, anchors: jax.Array, interpret: bool = Fa
     grid = (nq // QBLK, na // ABLK)
     return pl.pallas_call(
         _probe_kernel,
+        name="anchor_probe",
         grid=grid,
         in_specs=[
             pl.BlockSpec((QBLK, 1), lambda qi, ai: (qi, 0)),

@@ -88,6 +88,7 @@ def decode_rows_2d(gaps: jax.Array, base: jax.Array, lens: jax.Array,
     grid, rowspec, gspec = _specs(r, l)
     return pl.pallas_call(
         _decode_kernel,
+        name="fused_decode_rows",
         grid=grid,
         in_specs=[gspec, rowspec, rowspec],
         out_specs=[gspec, gspec],
@@ -111,6 +112,7 @@ def probe_rows_2d(gaps: jax.Array, base: jax.Array, lens: jax.Array,
     grid, rowspec, gspec = _specs(r, l)
     return pl.pallas_call(
         _probe_kernel,
+        name="fused_probe_rows",
         grid=grid,
         in_specs=[gspec, rowspec, rowspec, rowspec],
         out_specs=rowspec,

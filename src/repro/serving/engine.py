@@ -26,6 +26,7 @@ doc ids and dedups on device with a segment-max scan.
 from __future__ import annotations
 
 import warnings
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import jax
@@ -43,6 +44,7 @@ from ..core.anchors import (
 from ..core.doclist import BM25_B, BM25_K1, bm25_idf
 from ..core.index import NonPositionalIndex, PositionalIndex
 from ..core.registry import CAP_DEVICE_RESIDENT, capabilities_of
+from . import tracing
 from .plan import (  # noqa: F401  (re-exported: the legacy import surface)
     AND,
     DOCS,
@@ -426,33 +428,42 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
 
     def serve(index: dict, query_terms: jax.Array, query_lens: jax.Array,
               row_start: jax.Array | int = 0):
-        if fused:
-            idx = _as_compressed(index, max_phrase)
-            cand_vals, cand_valid = fused_candidates_for(
-                idx, query_terms[:, 0], row_start, decode=decode)
-        else:
-            idx = _as_anchored(index)
-            cand_vals, cand_valid = candidates_for(idx, query_terms[:, 0], row_start)
-        match = _probe_terms(idx, query_terms, query_lens, cand_vals, cand_valid,
-                             max_terms, phrase, member=member)
+        with jax.named_scope("candidates"):
+            if fused:
+                idx = _as_compressed(index, max_phrase)
+                cand_vals, cand_valid = fused_candidates_for(
+                    idx, query_terms[:, 0], row_start, decode=decode)
+            else:
+                idx = _as_anchored(index)
+                cand_vals, cand_valid = candidates_for(idx, query_terms[:, 0],
+                                                       row_start)
+        with jax.named_scope("probe"):
+            match = _probe_terms(idx, query_terms, query_lens, cand_vals,
+                                 cand_valid, max_terms, phrase, member=member)
         if doclist:
-            vals = cand_vals - 1
-            ds = index.get("doc_starts")
-            doc = vals if ds is None else jnp.searchsorted(ds, vals, side="right") - 1
-            doc = jnp.where(match, doc, -1)
-            # cummax, not associative_scan: over a (16, 2^20) window the TPU
-            # compiler finishes cummax in seconds and the scan not in 5 min
-            prev = jax.lax.cummax(doc, axis=1)
-            prev = jnp.concatenate(
-                [jnp.full((doc.shape[0], 1), -1, doc.dtype), prev[:, :-1]], axis=1)
-            return doc, match & (doc > prev)
+            with jax.named_scope("doclist_dedup"):
+                vals = cand_vals - 1
+                ds = index.get("doc_starts")
+                doc = (vals if ds is None
+                       else jnp.searchsorted(ds, vals, side="right") - 1)
+                doc = jnp.where(match, doc, -1)
+                # cummax, not associative_scan: over a (16, 2^20) window the
+                # TPU compiler finishes cummax in seconds and the scan not in
+                # 5 min
+                prev = jax.lax.cummax(doc, axis=1)
+                prev = jnp.concatenate(
+                    [jnp.full((doc.shape[0], 1), -1, doc.dtype), prev[:, :-1]],
+                    axis=1)
+                return doc, match & (doc > prev)
         if not topk:
             return cand_vals - 1, match
-        w = _idf_weights(idx, query_terms, query_lens, max_terms, n_docs)
-        scores = jnp.where(match, w[:, None], -jnp.inf)
-        top_scores, top_i = jax.lax.top_k(scores, topk)  # stable: ties → lowest index
-        top_vals = jnp.take_along_axis(cand_vals - 1, top_i, axis=1)
-        return top_vals, top_scores, top_scores > -jnp.inf
+        with jax.named_scope("topk"):
+            w = _idf_weights(idx, query_terms, query_lens, max_terms, n_docs)
+            scores = jnp.where(match, w[:, None], -jnp.inf)
+            # stable: ties → lowest index
+            top_scores, top_i = jax.lax.top_k(scores, topk)
+            top_vals = jnp.take_along_axis(cand_vals - 1, top_i, axis=1)
+            return top_vals, top_scores, top_scores > -jnp.inf
 
     return serve
 
@@ -475,17 +486,20 @@ def make_ranked_step(max_terms: int = 8, topk: int = 10):
         del row_start  # dense scoring has no candidate window to sweep
         b = query_terms.shape[0]
         doc_norm = index["rank_doc_norm"]  # (n_docs,) k1*(1-b+b*dl/avgdl)
-        scores = jnp.zeros((b, doc_norm.shape[0]), jnp.float32)
-        rows = jnp.arange(b)[:, None]
-        for t in range(max_terms):
-            term = query_terms[:, t]
-            docs = index["rank_run_docs"][term]  # (B, Lmax)
-            tfs = index["rank_run_tfs"][term]
-            live = index["rank_run_valid"][term] & (t < query_lens)[:, None]
-            contrib = (index["rank_idf"][term][:, None] * tfs * (BM25_K1 + 1.0)
-                       / (tfs + doc_norm[docs]))
-            scores = scores.at[rows, docs].add(jnp.where(live, contrib, 0.0))
-        top_scores, top_docs = jax.lax.top_k(scores, topk)
+        with jax.named_scope("bm25_scatter"):
+            scores = jnp.zeros((b, doc_norm.shape[0]), jnp.float32)
+            rows = jnp.arange(b)[:, None]
+            for t in range(max_terms):
+                term = query_terms[:, t]
+                docs = index["rank_run_docs"][term]  # (B, Lmax)
+                tfs = index["rank_run_tfs"][term]
+                live = index["rank_run_valid"][term] & (t < query_lens)[:, None]
+                contrib = (index["rank_idf"][term][:, None] * tfs
+                           * (BM25_K1 + 1.0) / (tfs + doc_norm[docs]))
+                scores = scores.at[rows, docs].add(
+                    jnp.where(live, contrib, 0.0))
+        with jax.named_scope("topk"):
+            top_scores, top_docs = jax.lax.top_k(scores, topk)
         return top_docs, top_scores, top_scores > 0.0
 
     return serve
@@ -523,15 +537,19 @@ class BatchedServer:
     _steps: dict = field(default_factory=dict)
     trace_events: int = 0
     # host-side copies of the immutable planning arrays, so encode /
-    # window counting never does a device->host transfer per batch
+    # window counting / lane counting never does a device->host transfer
+    # per batch
     _lengths_np: np.ndarray | None = None
     _c_offsets_np: np.ndarray | None = None
+    _lane_cum_np: np.ndarray | None = None
 
     def __post_init__(self):
         if self._lengths_np is None:
             self._lengths_np = np.asarray(self.arrays["lengths"])
         if self._c_offsets_np is None:
             self._c_offsets_np = np.asarray(self.arrays["c_offsets"])
+        if self._lane_cum_np is None:
+            self._lane_cum_np = lane_cumsum(self.arrays)
 
     #: posting-layout array names (device-memory accounting; rank_* and
     #: doc_starts are layout-independent extras)
@@ -631,8 +649,9 @@ class BatchedServer:
     def encode(self, queries: list[list[str]], sort_by_length: bool = False,
                width: int | None = None) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """See :func:`encode_queries` (the shared driver encode step)."""
-        return encode_queries(self.host_index, self._lengths_np, queries,
-                              sort_by_length=sort_by_length, width=width)
+        with tracing.span("engine.encode"):
+            return encode_queries(self.host_index, self._lengths_np, queries,
+                                  sort_by_length=sort_by_length, width=width)
 
     def _step(self, kind: str, width: int, topk: int = 0, doclist: bool = False):
         key = (kind, width, topk, doclist)
@@ -652,6 +671,9 @@ class BatchedServer:
                 self.trace_events += 1
                 return _raw(index, query_terms, query_lens, row_start)
 
+            # the name of the XLA module, which the device trace shows
+            counted.__name__ = counted.__qualname__ = step_name(kind, topk,
+                                                                doclist)
             self._steps[key] = jax.jit(counted)
         return self._steps[key]
 
@@ -661,6 +683,14 @@ class BatchedServer:
         rows = c_off[first + 1] - c_off[first]
         return max(1, int(-(-int(rows.max()) // MAX_CAND_ROWS)))
 
+    def _lanes(self, qt: np.ndarray, ok: np.ndarray, w: int) -> tuple[int, int]:
+        """(padded lanes, live lanes) of sweep window ``w`` (host copies)."""
+        per_row = (max(int(self.max_phrase), 1) if self.layout == "fused"
+                   else int(self.arrays["expand"].shape[-1]))
+        live = window_live_lanes(self._c_offsets_np, self._lane_cum_np,
+                                 qt[:, 0][ok], w * MAX_CAND_ROWS)
+        return qt.shape[0] * MAX_CAND_ROWS * per_row, live
+
     def _sweep(self, kind: str, queries: list[list[str]],
                width: int | None = None) -> list[np.ndarray]:
         qt, ql, ok = self.encode(queries, sort_by_length=(kind != PHRASE),
@@ -668,15 +698,12 @@ class BatchedServer:
         step = self._step(kind, qt.shape[1])
         hits: list[list[np.ndarray]] = [[] for _ in queries]
         for w in range(self._n_windows(qt, ok)):
-            vals, mask = step(self.arrays, jnp.asarray(qt), jnp.asarray(ql),
-                              w * MAX_CAND_ROWS)
-            vals, mask = np.asarray(vals), np.asarray(mask)
-            for i in range(len(queries)):
-                if ok[i]:
-                    hits[i].append(vals[i][mask[i]])
-        empty = np.zeros(0, np.int64)
-        return [np.unique(np.concatenate(h)).astype(np.int64) if (o and h) else empty
-                for h, o in zip(hits, ok)]
+            with step_window(self, step, self.arrays, qt, ql, w,
+                             self._lanes(qt, ok, w)) as (vals, mask):
+                for i in range(len(queries)):
+                    if ok[i]:
+                        hits[i].append(vals[i][mask[i]])
+        return _merge(hits, ok)
 
     # -- public batched entry points ------------------------------------
     def conjunctive(self, queries: list[list[str]],
@@ -703,15 +730,12 @@ class BatchedServer:
         step = self._step(kind, qt.shape[1], doclist=True)
         hits: list[list[np.ndarray]] = [[] for _ in queries]
         for w in range(self._n_windows(qt, ok)):
-            docs, keep = step(self.arrays, jnp.asarray(qt), jnp.asarray(ql),
-                              w * MAX_CAND_ROWS)
-            docs, keep = np.asarray(docs), np.asarray(keep)
-            for i in range(len(queries)):
-                if ok[i]:
-                    hits[i].append(docs[i][keep[i]])
-        empty = np.zeros(0, np.int64)
-        return [np.unique(np.concatenate(h)).astype(np.int64) if (o and h) else empty
-                for h, o in zip(hits, ok)]
+            with step_window(self, step, self.arrays, qt, ql, w,
+                             self._lanes(qt, ok, w)) as (docs, keep):
+                for i in range(len(queries)):
+                    if ok[i]:
+                        hits[i].append(docs[i][keep[i]])
+        return _merge(hits, ok)
 
     def topk(self, queries: list[list[str]], k: int = 10,
              width: int | None = None) -> list[np.ndarray]:
@@ -723,18 +747,18 @@ class BatchedServer:
         got: list[list[np.ndarray]] = [[] for _ in queries]
         counts = np.zeros(len(queries), np.int64)
         for w in range(self._n_windows(qt, ok)):
-            vals, scores, valid = step(self.arrays, jnp.asarray(qt), jnp.asarray(ql),
-                                       w * MAX_CAND_ROWS)
-            vals, valid = np.asarray(vals), np.asarray(valid)
-            for i in range(len(queries)):
-                if ok[i]:
-                    got[i].append(vals[i][valid[i]])
-            counts[ok] += valid[ok].sum(axis=1)
+            with step_window(self, step, self.arrays, qt, ql, w,
+                             self._lanes(qt, ok, w), fetch=(0, 2)) as (vals, valid):
+                for i in range(len(queries)):
+                    if ok[i]:
+                        got[i].append(vals[i][valid[i]])
+                counts[ok] += valid[ok].sum(axis=1)
             if (counts >= k)[ok].all():
                 break
-        empty = np.zeros(0, np.int64)
-        return [np.concatenate(g)[:k].astype(np.int64) if (o and g) else empty
-                for g, o in zip(got, ok)]
+        with tracing.span("engine.merge"):
+            empty = np.zeros(0, np.int64)
+            return [np.concatenate(g)[:k].astype(np.int64) if (o and g) else empty
+                    for g, o in zip(got, ok)]
 
     def ranked(self, queries: list[list[str]], k: int = 10,
                width: int | None = None) -> list[np.ndarray]:
@@ -753,8 +777,77 @@ class BatchedServer:
         qt, ql, ok = self.encode(queries, width=width)
         eff_k = min(int(k), int(self.arrays["rank_doc_norm"].shape[0]))
         step = self._step(RANK, qt.shape[1], topk=eff_k)
-        docs, _scores, valid = step(self.arrays, jnp.asarray(qt), jnp.asarray(ql))
-        docs, valid = np.asarray(docs), np.asarray(valid)
+        # lanes: the (doc, tf) run slots the step gathers; live: the
+        # postings of the active queries' terms
+        active = np.arange(qt.shape[1])[None, :] < ql[:, None]
+        lanes = (qt.size * int(self.arrays["rank_run_docs"].shape[1]),
+                 int(self._lengths_np[qt][active & ok[:, None]].sum()))
+        with step_window(self, step, self.arrays, qt, ql, 0, lanes,
+                         fetch=(0, 2)) as (docs, valid):
+            empty = np.zeros(0, np.int64)
+            return [docs[i][valid[i]].astype(np.int64) if ok[i] else empty
+                    for i in range(len(queries))]
+
+
+@contextmanager
+def step_window(server, step, arrays: dict, qt: np.ndarray, ql: np.ndarray,
+                w: int, lanes: tuple[int, int], fetch: tuple[int, ...] = (0, 1)):
+    """Run one call of ``server``'s ``step`` over sweep window ``w`` under
+    an ``engine.window`` span and yield the host copies of its outputs
+    ``fetch``; the caller's per-query masking runs inside the
+    ``engine.fetch`` span.  ``lanes`` is (padded lanes, live lanes)."""
+    traces = server.trace_events
+    with tracing.span("engine.window", program=step.__name__, window=w,
+                      lanes=lanes[0], live_lanes=lanes[1]) as sp:
+        outs = step(arrays, jnp.asarray(qt), jnp.asarray(ql), w * MAX_CAND_ROWS)
+        sp.set(traced=int(server.trace_events > traces))
+        outs = [outs[i] for i in fetch]
+        with tracing.span("engine.device_wait"):
+            jax.block_until_ready(outs)
+        with tracing.span("engine.fetch") as f:
+            host = [np.asarray(o) for o in outs]
+            f.set(bytes=sum(h.nbytes for h in host))
+            yield host
+
+
+def step_name(kind: str, topk: int = 0, doclist: bool = False) -> str:
+    """The name of a jitted step, after what it serves: ``serve_and``,
+    ``serve_phrase``, ``serve_and_docs``, ``serve_phrase_docs``,
+    ``serve_and_top``, ``rank_bm25``."""
+    if kind == RANK:
+        return "rank_bm25"
+    return (f"serve_{PHRASE if kind == PHRASE else AND}"
+            + ("_docs" if doclist else "") + ("_top" if topk else ""))
+
+
+def lane_cumsum(arrays: dict) -> np.ndarray:
+    """Host copy of the decoded postings up to each C entry (a leading
+    shard axis is kept): ``c_len`` on the fused layout, the valid lanes of
+    each expand row on the dense one.  Made once, at construction."""
+    rows = (np.asarray(arrays["c_len"]) if "c_len" in arrays
+            else np.asarray(arrays["expand_valid"]).sum(axis=-1))
+    cum = np.zeros(rows.shape[:-1] + (rows.shape[-1] + 1,), np.int64)
+    np.cumsum(rows, axis=-1, out=cum[..., 1:])
+    return cum
+
+
+def window_live_lanes(c_offsets: np.ndarray, lane_cum: np.ndarray,
+                      first: np.ndarray, row_start: int) -> int:
+    """Decoded postings in the candidate window at ``row_start`` of the
+    lists ``first``: their C entries [row_start, row_start + MAX_CAND_ROWS)
+    that exist.  With a leading shard axis on ``c_offsets`` and
+    ``lane_cum`` the count sums over the shards."""
+    lo = c_offsets[..., first]
+    hi = c_offsets[..., first + 1]
+    a = np.minimum(lo + row_start, hi)
+    b = np.minimum(lo + row_start + MAX_CAND_ROWS, hi)
+    return int((np.take_along_axis(lane_cum, b, -1)
+                - np.take_along_axis(lane_cum, a, -1)).sum())
+
+
+def _merge(hits: list[list[np.ndarray]], ok: np.ndarray) -> list[np.ndarray]:
+    """The per-batch union of each query's window answers."""
+    with tracing.span("engine.merge"):
         empty = np.zeros(0, np.int64)
-        return [docs[i][valid[i]].astype(np.int64) if ok[i] else empty
-                for i in range(len(queries))]
+        return [np.unique(np.concatenate(h)).astype(np.int64) if (o and h)
+                else empty for h, o in zip(hits, ok)]

@@ -60,6 +60,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from . import tracing
 from .plan import RANK, SERVER_KINDS, ParsedQuery, parse_query, width_bucket
 from .session import Session
 
@@ -411,7 +412,8 @@ class _Pending:
     pq: ParsedQuery
     key: tuple
     future: asyncio.Future
-    submitted_at: float
+    request: tracing.Span  # its frontend.request span, open until answered
+    batch: int = -1  # the micro-batch it rides, once flushed
 
 
 class MicroBatchFrontend:
@@ -461,30 +463,35 @@ class MicroBatchFrontend:
         Cache hits return immediately; otherwise the query joins its
         width bucket and rides the next micro-batch.  Raises
         :class:`FrontendOverloaded` when the bounded queue is full and
-        :class:`FrontendClosed` after :meth:`close`."""
+        :class:`FrontendClosed` after :meth:`close`.  The latency recorded
+        is the duration of the request's ``frontend.request`` span."""
         if self._closed:
             raise FrontendClosed("frontend is closed")
-        t0 = time.perf_counter()
+        with tracing.span("frontend.request", request=tracing.next_id()) as req:
+            result = await self._admit(q, req)
+        self.recorder.record(req.seconds, depth=self.depth)
+        return result
+
+    async def _admit(self, q, req: tracing.Span) -> np.ndarray:
         pq = parse_query(q)
         key = self.session.result_key(pq)
         self.submitted += 1
         cached = self.cache.get(key)
         if cached is not None:
             self.cache_served += 1
-            self.recorder.record(time.perf_counter() - t0, depth=self.depth)
             return cached
         inflight = self._pending_by_key.get(key)
         if inflight is not None:  # identical query already pending: coalesce
             self.coalesced += 1
             result = await inflight.future
-            self.recorder.record(time.perf_counter() - t0, depth=self.depth)
+            req.set(batch=inflight.batch)
             return result
         if self.depth >= self.config.max_pending:
             self.rejected += 1
             raise FrontendOverloaded(self.depth, self.config.max_pending)
         self._loop = asyncio.get_running_loop()
         pend = _Pending(pq=pq, key=key, future=self._loop.create_future(),
-                        submitted_at=t0)
+                        request=req)
         # the same power-of-two width buckets the Session's plan cache and
         # jit traces are keyed on — a flushed bucket is one shape
         bucket = (pq.kind, pq.k, pq.phrase, width_bucket(len(pq.terms)))
@@ -497,9 +504,7 @@ class MicroBatchFrontend:
         elif bucket not in self._timers:
             self._timers[bucket] = self._loop.call_later(
                 self.config.max_delay, self._flush, bucket, "deadline")
-        result = await pend.future
-        self.recorder.record(time.perf_counter() - t0, depth=self.depth)
-        return result
+        return await pend.future
 
     @property
     def depth(self) -> int:
@@ -520,12 +525,29 @@ class MicroBatchFrontend:
         self.batches += 1
         self.batched_queries += len(pend)
         self.max_batch_seen = max(self.max_batch_seen, len(pend))
+        batch = tracing.next_id()
+        for p in pend:
+            p.batch = batch
+            p.request.set(batch=batch)
         self._loop.create_task(self._run_batch(pend))
+
+    def _execute(self, pend: list[_Pending]) -> list:
+        """One micro-batch on the executor thread.  ``run_in_executor``
+        carries no context variables over, so the batch's spans take its id
+        and their parents from ``pend`` itself."""
+        begun = time.perf_counter()
+        batch = pend[0].batch
+        for p in pend:
+            tracing.record("frontend.queue", p.request.start, begun,
+                           parent=p.request.id,
+                           request=p.request.attrs["request"], batch=batch)
+        with tracing.span("frontend.batch", batch=batch, queries=len(pend)):
+            return self.session.execute([p.pq for p in pend])
 
     async def _run_batch(self, pend: list[_Pending]) -> None:
         try:
             results = await self._loop.run_in_executor(
-                self._executor, self.session.execute, [p.pq for p in pend])
+                self._executor, self._execute, pend)
         except Exception as e:
             for p in pend:
                 if not p.future.done():

@@ -33,7 +33,17 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core.anchors import AnchoredIndex, build_anchored
-from .engine import MAX_CAND_ROWS, _probe_terms, candidates_for, encode_queries
+from . import tracing
+from .engine import (
+    MAX_CAND_ROWS,
+    _merge,
+    _probe_terms,
+    candidates_for,
+    encode_queries,
+    lane_cumsum,
+    step_window,
+    window_live_lanes,
+)
 
 
 @dataclass
@@ -213,12 +223,15 @@ class PartitionedServer:
     trace_events: int = 0
     _lengths_np: np.ndarray | None = None  # global lengths: sum over shards
     _c_offsets_np: np.ndarray | None = None  # (S, T+1) per-shard C-offsets
+    _lane_cum_np: np.ndarray | None = None  # (S, n_c+1) decoded postings
 
     def __post_init__(self):
         if self._lengths_np is None:
             self._lengths_np = np.asarray(self.pidx.arrays["lengths"]).sum(axis=0)
         if self._c_offsets_np is None:
             self._c_offsets_np = np.asarray(self.pidx.arrays["c_offsets"])
+        if self._lane_cum_np is None:
+            self._lane_cum_np = lane_cumsum(self.pidx.arrays)
 
     @classmethod
     def from_index(cls, index, n_shards: int, mesh=None,
@@ -256,8 +269,9 @@ class PartitionedServer:
         """Pad to (B, width) global term ids (the shared
         :func:`~repro.serving.engine.encode_queries` step; lengths for the
         rarest-first sort are the shard-summed global list lengths)."""
-        return encode_queries(self.host_index, self._lengths_np, queries,
-                              sort_by_length=sort_by_length, width=width)
+        with tracing.span("engine.encode"):
+            return encode_queries(self.host_index, self._lengths_np, queries,
+                                  sort_by_length=sort_by_length, width=width)
 
     def _step(self, mode: str, width: int):
         key = (mode, width)
@@ -272,6 +286,7 @@ class PartitionedServer:
                     self.trace_events += 1
                     return _raw(arrays, qt, ql, row_start)
 
+                counted.__name__ = counted.__qualname__ = f"serve_{mode}_sharded"
                 serve = jax.jit(counted)
             else:
                 def local(local_arrays, qt, ql, row_start, _mode=mode, _w=width):
@@ -280,6 +295,7 @@ class PartitionedServer:
                     return _local_serve(local_arrays, qt, ql, _w, mode=_mode,
                                         row_start=row_start)
 
+                local.__name__ = local.__qualname__ = f"serve_{mode}_shard"
                 jitted = jax.jit(local)
 
                 def serve(arrays, qt, ql, row_start, _j=jitted):
@@ -292,6 +308,8 @@ class PartitionedServer:
                     vals = jnp.stack([v for v, _ in outs])
                     mask = jnp.stack([m for _, m in outs])
                     return vals, mask
+
+                serve.__name__ = local.__name__
             self._steps[key] = serve
         return self._steps[key]
 
@@ -303,17 +321,18 @@ class PartitionedServer:
         c = self._c_offsets_np
         first = qt[:, 0][ok] if ok.any() else qt[:1, 0]
         rows = int((c[:, first + 1] - c[:, first]).max())
+        per_row = int(self.pidx.arrays["expand"].shape[-1])
         hits: list[list[np.ndarray]] = [[] for _ in queries]
         for w in range(max(1, -(-rows // MAX_CAND_ROWS))):
-            vals, mask = serve(self.pidx.arrays, jnp.asarray(qt),
-                               jnp.asarray(ql), w * MAX_CAND_ROWS)
-            vals, mask = np.asarray(vals), np.asarray(mask)
-            for qi in range(len(queries)):
-                if ok[qi]:
-                    hits[qi].append(vals[:, qi][mask[:, qi]])
-        empty = np.zeros(0, np.int64)
-        return [np.unique(np.concatenate(h)).astype(np.int64) if (o and h) else empty
-                for h, o in zip(hits, ok)]
+            lanes = (self.pidx.n_shards * qt.shape[0] * MAX_CAND_ROWS * per_row,
+                     window_live_lanes(c, self._lane_cum_np, qt[:, 0][ok],
+                                       w * MAX_CAND_ROWS))
+            with step_window(self, serve, self.pidx.arrays, qt, ql, w,
+                             lanes) as (vals, mask):
+                for qi in range(len(queries)):
+                    if ok[qi]:
+                        hits[qi].append(vals[:, qi][mask[:, qi]])
+        return _merge(hits, ok)
 
     def conjunctive(self, queries: list[list[str]],
                     width: int | None = None) -> list[np.ndarray]:

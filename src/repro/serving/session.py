@@ -62,6 +62,7 @@ from ..core.doclist import (
 )
 from ..core.index import NonPositionalIndex, PositionalIndex
 from ..core.writer import IndexWriter, is_writer_dir
+from . import tracing
 from .plan import (
     AND,
     DOCS,
@@ -416,27 +417,36 @@ class Session:
         offsets."""
         single = isinstance(queries, (str, ParsedQuery))
         batch = [queries] if single else list(queries)
-        parsed = [self._parse(q) for q in batch]
-        # snapshot: refresh() replaces (never mutates) the segment list, so
-        # one execute answers against exactly one committed segment set
-        # even when another thread refreshes mid-query
-        segs = self._segments
-        if segs:
-            for pq in parsed:
-                self.plan(pq)  # warm/count the segment-shape route cache
-            self.queries_executed += len(batch)
-            out = self._execute_segmented(parsed, segs)
-            return out[0] if single else out
-        routes = [self.plan(pq) for pq in parsed]
-        self.queries_executed += len(batch)
-        out: list[np.ndarray | None] = [None] * len(batch)
-        groups: dict[tuple, list[int]] = {}
-        for i, (pq, rt) in enumerate(zip(parsed, routes)):
-            if rt.route == "device":
-                key = (rt.index, pq.kind, pq.k, pq.phrase, rt.width)
-                groups.setdefault(key, []).append(i)
+        with tracing.span("session.execute", queries=len(batch)):
+            out = self._execute(batch)
+        return out[0] if single else out
+
+    def _execute(self, batch: list) -> list[np.ndarray]:
+        with tracing.span("session.plan"):
+            parsed = [self._parse(q) for q in batch]
+            # snapshot: refresh() replaces (never mutates) the segment list,
+            # so one execute answers against exactly one committed segment
+            # set even when another thread refreshes mid-query
+            segs = self._segments
+            if segs:
+                for pq in parsed:
+                    self.plan(pq)  # warm/count the segment-shape route cache
             else:
-                out[i] = self._execute_host(pq)
+                routes = [self.plan(pq) for pq in parsed]
+                host: list[int] = []
+                groups: dict[tuple, list[int]] = {}
+                for i, (pq, rt) in enumerate(zip(parsed, routes)):
+                    if rt.route == "device":
+                        key = (rt.index, pq.kind, pq.k, pq.phrase, rt.width)
+                        groups.setdefault(key, []).append(i)
+                    else:
+                        host.append(i)
+        self.queries_executed += len(batch)
+        if segs:
+            return self._execute_segmented(parsed, segs)
+        out: list[np.ndarray | None] = [None] * len(batch)
+        for i in host:
+            out[i] = self._execute_host(parsed[i])
         for (index_name, kind, k, phrase, width), idxs in groups.items():
             server = self.server if index_name == "nonpositional" else self.positional_server
             sub = [list(parsed[i].terms) for i in idxs]
@@ -453,7 +463,7 @@ class Session:
             self.device_batches += 1
             for i, r in zip(idxs, res):
                 out[i] = r
-        return out[0] if single else out
+        return out
 
     # -- segment-aware merge (doc ids shift by doc_base, positions by
     # token_base; a document lives in exactly one segment, so per-doc

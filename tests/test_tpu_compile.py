@@ -151,6 +151,27 @@ def test_fused_serve_step_fits_v5e(one_chip, mode, doclist, topk):
     _fits(_compile(step, index, *_queries(one_chip, BATCH)))
 
 
+def test_named_and_step_compiles_for_v5e_at_the_cell_shape(one_chip):
+    """The server's own AND step, under its program name and scopes, at
+    the benchmark cell's window: a batch of 16 over a max_phrase of
+    16,384."""
+    import numpy as np
+
+    from repro.serving.engine import AND, BatchedServer
+
+    server = BatchedServer(host_index=None, arrays={}, n_docs=float(MAIN_DOCS),
+                           layout="fused", max_phrase=NP_MAX_PHRASE,
+                           _lengths_np=np.zeros(0), _c_offsets_np=np.zeros(1),
+                           _lane_cum_np=np.zeros(1))
+    index = _fused_index(one_chip, MAIN_DOCS, NP_ANCHORS, NP_POOL,
+                         NP_MAX_PHRASE, positional=False)
+    compiled = server._step(AND, 2).lower(index, *_queries(one_chip, 16)).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_serve_and")
+    assert "anchor_search" in text and "pool_search" in text
+    _fits(compiled)
+
+
 def test_kernel_serve_step_compiles_for_v5e(one_chip, monkeypatch):
     """probe="kernel" at the kernel phase's sizes holds compiled Pallas
     kernels (the step asks the backend whether to interpret; here the
