@@ -73,12 +73,12 @@ MAIN_WHY = {
     "pos_docs": "cut from np_docs: the positional host build is superlinear "
                 "(13 s at 2,000 docs, 58 s at 4,000 on an 8-core host)",
     "per_kind": "one device batch per kind: the fused window holds "
-                "(batch*64, max_phrase) int32, and max_phrase grows with "
-                "np_docs (16,384 at 50,000 docs)",
+                "(batch, max_phrase rounded up to 1,024) int32, and "
+                "max_phrase grows with np_docs (16,384 at 50,000 docs)",
 }
 KERNELS = dict(docs=300, per_kind=4)
 KERNELS_WHY = ("cut from the main path: the fused kernel probe gathers a "
-               "max_phrase-wide row per probe (batch*64*max_phrase^2 int32) "
+               "max_phrase-wide row per probe (batch*max_phrase^2 int32) "
                "and anchor_probe_sliced compares every probe with every anchor")
 MINING = dict(docs=2_000, rlz_docs=2_000, probes=8)
 FOUR_CHIPS = dict(np_docs=4_000, pos_docs=1_000, per_kind=16)
@@ -280,8 +280,9 @@ def kernel_phase(docs: int, per_kind: int, seed: int) -> dict:
         check(session.device_batches > 0, f"{layout}: no device batch ran")
         srv = session.server
         qt, ql, _ = srv.encode([list(parse_query(queries[0]).terms)], width=2)
+        cursor, _ = next(srv._windows(qt, np.ones(len(qt), bool)))
         text = srv._step(AND, qt.shape[1]).lower(
-            srv.arrays, jnp.asarray(qt), jnp.asarray(ql), 0).as_text()
+            srv.arrays, jnp.asarray(qt), jnp.asarray(ql), *cursor).as_text()
         found[layout] = "tpu_custom_call" in text
         print(f"  {layout} AND step lowered: tpu_custom_call "
               f"{'present' if found[layout] else 'absent'}")

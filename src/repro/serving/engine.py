@@ -17,9 +17,12 @@ table and probes the remaining terms through the anchored binary search
 (``member_batch``).  Phrase queries probe *shifted* candidates
 (offset-shifted intersection, paper §3): term ``t`` of a phrase must hold
 ``position + t``.  Candidate generation is **windowed**: the host driver
-sweeps ``row_start`` over the driving list's C-entries so arbitrarily long
-lists are served exactly.  Ranked top-k computes idf-proxy weights on
-device and reduces with ``lax.top_k``; document listing maps matches to
+sweeps a cursor over the driving list's C-entries so arbitrarily long
+lists are served exactly.  On the dense layout a window is MAX_CAND_ROWS
+expand rows of every list; on the fused layout it is budgeted in postings,
+each query's C entries ``[row_lo, row_hi)`` expanded into
+``window_lanes(max_phrase)`` lanes.  Ranked top-k computes idf-proxy
+weights on device and reduces with ``lax.top_k``; document listing maps matches to
 doc ids and dedups on device with a segment-max scan.
 """
 
@@ -237,41 +240,55 @@ def candidates_for(idx: AnchoredIndex, list_ids: jax.Array,
 
 _PAD_VAL = 2**31 - 1  # anchor_intersect's sentinel (shifted targets stay below)
 
+#: a fused window's lanes per query are a whole number of these
+WINDOW_LANE_TILE = 1024
 
-def fused_candidates_for(idx: CompressedAnchoredIndex, list_ids: jax.Array,
-                         row_start: jax.Array | int = 0,
-                         decode=None) -> tuple[jax.Array, jax.Array]:
-    """Fused-layout counterpart of :func:`candidates_for`: the same
-    MAX_CAND_ROWS window, but each C entry decodes from the shared
-    prefix-summed pool (bounded by ``max_phrase``) instead of reading a
-    dense expand row.
 
-    ``decode`` swaps the decode implementation (inline anchor re-base by
-    default; the Pallas ``fused_decode`` kernel via ``probe="kernel"``).
-    Returns (values (B, C), valid (B, C)) in cumulative-gap space —
-    identical to the dense generator's output for the same store.
+def window_lanes(max_phrase: int) -> int:
+    """Lanes per query of a fused-layout sweep window: ``max_phrase``
+    rounded up to a multiple of WINDOW_LANE_TILE.  No C entry expands to
+    more than ``max_phrase`` postings, so every entry fits one window and
+    the sweep always advances."""
+    tiles = -(-max(int(max_phrase), 1) // WINDOW_LANE_TILE)
+    return tiles * WINDOW_LANE_TILE
+
+
+def fused_candidates_for(idx: CompressedAnchoredIndex, row_lo: jax.Array,
+                         row_hi: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Fused-layout candidate generator: the postings of each query's C
+    entries ``[row_lo, row_hi)`` (absolute entry numbers, int32[B]),
+    expanded from the shared prefix-summed pool into
+    ``window_lanes(idx.max_phrase)`` lanes, packed from lane 0.
+
+    The host planner keeps each range's postings within the lanes, so the
+    expansion is ragged with no per-entry padding: lane ``k`` belongs to
+    the last entry that starts at or before it (a scatter of each entry's
+    index at its start, carried right by ``cummax``).  Returns (values
+    (B, lanes), valid (B, lanes)) in cumulative-gap space, ascending along
+    each query's valid lanes — the dense generator's postings for the
+    same entries, without its padding.
     """
-    lo = idx.c_offsets[list_ids] + row_start
-    hi = idx.c_offsets[list_ids + 1]
-    rows = lo[:, None] + jnp.arange(MAX_CAND_ROWS)[None, :]
-    valid_rows = rows < hi[:, None]
+    lanes = window_lanes(idx.max_phrase)
+    b = row_lo.shape[0]
+    k = jnp.arange(lanes, dtype=jnp.int32)
+    # every entry holds at least one posting, so at most `lanes` entries fit
+    rows = row_lo[:, None] + k[None, :]
+    live = rows < row_hi[:, None]
     rows = jnp.minimum(rows, idx.anchors.shape[0] - 1)
-    flat = rows.reshape(-1)
-    L = max(int(idx.max_phrase), 1)
-    base = idx.anchors[flat]
-    lens = jnp.where(valid_rows.reshape(-1), idx.c_len[flat], 0)
-    # (B*ROWS, L) contiguous prefix-sum row slices from the padded pool
-    # (the ragged gather stays outside the kernel)
-    psums = jax.vmap(
-        lambda p: jax.lax.dynamic_slice_in_dim(idx.pool, p, L)
-    )(idx.c_ptr[flat])
-    if decode is None:
-        valid = jnp.arange(L, dtype=jnp.int32)[None, :] < lens[:, None]
-        vals = base[:, None] + psums
-    else:
-        vals, valid = decode(psums, base, lens)
-    b = list_ids.shape[0]
-    return vals.reshape(b, -1), valid.reshape(b, -1)
+    lens = jnp.where(live, idx.c_len[rows], 0)
+    starts = jnp.cumsum(lens, axis=1) - lens
+    total = starts[:, -1] + lens[:, -1]
+    # cummax, not associative_scan, for the reason doclist_dedup gives;
+    # masked entries scatter 0, which the max ignores
+    owner = jnp.zeros((b, lanes), jnp.int32).at[
+        jnp.arange(b)[:, None], starts].max(jnp.where(live, k, 0), mode="drop")
+    owner = jax.lax.cummax(owner, axis=1)
+    base = jnp.take_along_axis(idx.anchors[rows], owner, axis=1)
+    # pool slot of lane k: its entry's pointer plus (k - the entry's start)
+    slot = jnp.take_along_axis(idx.c_ptr[rows] - starts, owner, axis=1) + k
+    valid = k[None, :] < total[:, None]
+    vals = base + idx.pool[jnp.where(valid, slot, 0)]
+    return vals, valid
 
 
 def _probe_terms(idx, query_terms, query_lens, cand_vals, cand_valid,
@@ -389,10 +406,15 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
     """Build a batched device step.
 
     ``mode`` is "and" (conjunctive doc queries) or "phrase" (offset-shifted
-    positional probes).  With ``topk == 0`` the step returns
-    ``(candidate postings (B, C), match mask (B, C))`` for the window at
-    ``row_start``; with ``topk == k`` it additionally ranks on device and
-    returns ``(top postings (B, k), top scores (B, k), top valid (B, k))``.
+    positional probes).  The step is called as ``serve(index, query_terms,
+    query_lens, *cursor)``, where the cursor names the sweep window: on
+    the dense layout ``row_start``, the window's first C entry within
+    every driving list (default 0); on the fused layout ``row_lo,
+    row_hi``, each query's absolute C-entry range, int32[B] (see
+    :func:`fused_candidates_for`).  With ``topk == 0`` the step returns
+    ``(candidate postings (B, C), match mask (B, C))`` for the window;
+    with ``topk == k`` it additionally ranks on device and returns
+    ``(top postings (B, k), top scores (B, k), top valid (B, k))``.
     With ``doclist=True`` the step returns ``(doc ids (B, C), keep (B, C))``:
     matching positions map to documents through the ``doc_starts`` array in
     ``index`` (identity when absent — non-positional postings are doc ids)
@@ -402,41 +424,35 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
     before it.  ``probe="kernel"`` routes the inner membership probes
     through the Pallas kernels (interpret mode off-TPU):
     ``anchor_intersect`` tiled compares for the dense layout, plus
-    ``fused_decode`` expansion for the fused one (compiled on the TPU,
+    ``fused_decode`` row probes for the fused one (compiled on the TPU,
     interpreted on the CPU; see ``kernels.platform.interpret_mode``).
 
     ``layout`` selects the device memory model: "dense" reads the
     ``(n_c, expand_len)`` expand tables; "fused" keeps only the compressed
     arrays (anchors + rule-pool pointers, bound ``max_phrase``) in HBM and
-    decodes inside the sweep — byte-identical results either way.
+    expands each window's entries from the pool inside the step —
+    byte-identical results either way.
     """
     phrase = mode == PHRASE
     fused = layout == "fused"
     member = None
-    decode = None
     if probe == "kernel":
         from ..kernels.platform import interpret_mode
 
         interpret = interpret_mode()
-        if fused:
-            from ..kernels.fused_decode.ops import decode_rows
-
-            member = _kernel_member_fused(interpret=interpret)
-            decode = lambda g, b, n: decode_rows(g, b, n, interpret=interpret)
-        else:
-            member = _kernel_member(interpret=interpret)
+        member = (_kernel_member_fused(interpret=interpret) if fused
+                  else _kernel_member(interpret=interpret))
 
     def serve(index: dict, query_terms: jax.Array, query_lens: jax.Array,
-              row_start: jax.Array | int = 0):
+              *cursor: jax.Array | int):
         with jax.named_scope("candidates"):
             if fused:
                 idx = _as_compressed(index, max_phrase)
-                cand_vals, cand_valid = fused_candidates_for(
-                    idx, query_terms[:, 0], row_start, decode=decode)
+                cand_vals, cand_valid = fused_candidates_for(idx, *cursor)
             else:
                 idx = _as_anchored(index)
                 cand_vals, cand_valid = candidates_for(idx, query_terms[:, 0],
-                                                       row_start)
+                                                       *cursor)
         with jax.named_scope("probe"):
             match = _probe_terms(idx, query_terms, query_lens, cand_vals,
                                  cand_valid, max_terms, phrase, member=member)
@@ -482,8 +498,8 @@ def make_ranked_step(max_terms: int = 8, topk: int = 10):
     """
 
     def serve(index: dict, query_terms: jax.Array, query_lens: jax.Array,
-              row_start: jax.Array | int = 0):
-        del row_start  # dense scoring has no candidate window to sweep
+              *cursor):
+        del cursor  # dense scoring has no candidate window to sweep
         b = query_terms.shape[0]
         doc_norm = index["rank_doc_norm"]  # (n_docs,) k1*(1-b+b*dl/avgdl)
         with jax.named_scope("bm25_scatter"):
@@ -517,8 +533,8 @@ def make_uihrdc_serve_step(max_terms: int = 8):
 @dataclass
 class BatchedServer:
     """Owns the device-resident anchored arrays for one index plus a cache
-    of jitted steps, and drives the candidate-window sweep so results are
-    exact for lists of any length (no 64-candidate truncation).
+    of jitted steps, and drives the candidate-window sweep (``_windows``)
+    so results are exact for lists of any length.
 
     ``trace_count`` counts actual jit traces (the counter increments inside
     the traced python body, which only runs on an XLA compile) — the
@@ -665,11 +681,11 @@ class BatchedServer:
                                       doclist=doclist, layout=self.layout,
                                       max_phrase=self.max_phrase)
 
-            def counted(index, query_terms, query_lens, row_start=0, _raw=raw):
+            def counted(index, query_terms, query_lens, *cursor):
                 # this body runs only while jax traces (i.e. on a compile),
                 # so the increment counts actual retraces
                 self.trace_events += 1
-                return _raw(index, query_terms, query_lens, row_start)
+                return raw(index, query_terms, query_lens, *cursor)
 
             # the name of the XLA module, which the device trace shows
             counted.__name__ = counted.__qualname__ = step_name(kind, topk,
@@ -677,19 +693,43 @@ class BatchedServer:
             self._steps[key] = jax.jit(counted)
         return self._steps[key]
 
-    def _n_windows(self, qt: np.ndarray, ok: np.ndarray) -> int:
-        c_off = self._c_offsets_np
-        first = qt[:, 0][ok] if ok.any() else qt[:1, 0]
-        rows = c_off[first + 1] - c_off[first]
-        return max(1, int(-(-int(rows.max()) // MAX_CAND_ROWS)))
+    def _windows(self, qt: np.ndarray, ok: np.ndarray):
+        """The sweep's windows over each query's driving list ``qt[:, 0]``,
+        at least one: ``(cursor, (padded lanes, live lanes))`` per step
+        call, from host copies only.
 
-    def _lanes(self, qt: np.ndarray, ok: np.ndarray, w: int) -> tuple[int, int]:
-        """(padded lanes, live lanes) of sweep window ``w`` (host copies)."""
-        per_row = (max(int(self.max_phrase), 1) if self.layout == "fused"
-                   else int(self.arrays["expand"].shape[-1]))
-        live = window_live_lanes(self._c_offsets_np, self._lane_cum_np,
-                                 qt[:, 0][ok], w * MAX_CAND_ROWS)
-        return qt.shape[0] * MAX_CAND_ROWS * per_row, live
+        Dense layout: MAX_CAND_ROWS expand rows of every list per window.
+        Fused layout: each query takes the C entries, from where its last
+        window stopped, whose postings fit ``window_lanes(max_phrase)``
+        lanes; a query with ``ok`` False or an exhausted list takes an
+        empty range."""
+        b, first = qt.shape[0], qt[:, 0]
+        c_off, cum = self._c_offsets_np, self._lane_cum_np
+        if self.layout != "fused":
+            per_row = int(self.arrays["expand"].shape[-1])
+            drive = first[ok] if ok.any() else first[:1]
+            rows = int((c_off[drive + 1] - c_off[drive]).max())
+            for w in range(max(1, -(-rows // MAX_CAND_ROWS))):
+                live = window_live_lanes(c_off, cum, first[ok],
+                                         w * MAX_CAND_ROWS)
+                yield (w * MAX_CAND_ROWS,), (b * MAX_CAND_ROWS * per_row, live)
+            return
+        lanes = window_lanes(self.max_phrase)
+        lo = np.where(ok, c_off[first], 0).astype(np.int64)
+        hi = np.where(ok, c_off[first + 1], 0).astype(np.int64)
+        while True:
+            # the furthest entry whose postings since lo fit the lanes
+            reach = np.searchsorted(cum, cum[lo] + lanes, side="right") - 1
+            end = np.minimum(np.minimum(reach, hi), lo + lanes)
+            yield ((lo.astype(np.int32), end.astype(np.int32)),
+                   (b * lanes, int((cum[end] - cum[lo]).sum())))
+            lo = end
+            if not (lo < hi).any():
+                return
+
+    def _n_windows(self, qt: np.ndarray, ok: np.ndarray) -> int:
+        """Step calls a sweep over the batch makes (topk may stop sooner)."""
+        return sum(1 for _ in self._windows(qt, ok))
 
     def _sweep(self, kind: str, queries: list[list[str]],
                width: int | None = None) -> list[np.ndarray]:
@@ -697,9 +737,9 @@ class BatchedServer:
                                  width=width)
         step = self._step(kind, qt.shape[1])
         hits: list[list[np.ndarray]] = [[] for _ in queries]
-        for w in range(self._n_windows(qt, ok)):
-            with step_window(self, step, self.arrays, qt, ql, w,
-                             self._lanes(qt, ok, w)) as (vals, mask):
+        for w, (cursor, lanes) in enumerate(self._windows(qt, ok)):
+            with step_window(self, step, self.arrays, qt, ql, w, cursor,
+                             lanes) as (vals, mask):
                 for i in range(len(queries)):
                     if ok[i]:
                         hits[i].append(vals[i][mask[i]])
@@ -729,9 +769,9 @@ class BatchedServer:
         qt, ql, ok = self.encode(queries, sort_by_length=not phrase, width=width)
         step = self._step(kind, qt.shape[1], doclist=True)
         hits: list[list[np.ndarray]] = [[] for _ in queries]
-        for w in range(self._n_windows(qt, ok)):
-            with step_window(self, step, self.arrays, qt, ql, w,
-                             self._lanes(qt, ok, w)) as (docs, keep):
+        for w, (cursor, lanes) in enumerate(self._windows(qt, ok)):
+            with step_window(self, step, self.arrays, qt, ql, w, cursor,
+                             lanes) as (docs, keep):
                 for i in range(len(queries)):
                     if ok[i]:
                         hits[i].append(docs[i][keep[i]])
@@ -746,9 +786,9 @@ class BatchedServer:
         step = self._step(AND, qt.shape[1], topk=int(k))
         got: list[list[np.ndarray]] = [[] for _ in queries]
         counts = np.zeros(len(queries), np.int64)
-        for w in range(self._n_windows(qt, ok)):
-            with step_window(self, step, self.arrays, qt, ql, w,
-                             self._lanes(qt, ok, w), fetch=(0, 2)) as (vals, valid):
+        for w, (cursor, lanes) in enumerate(self._windows(qt, ok)):
+            with step_window(self, step, self.arrays, qt, ql, w, cursor,
+                             lanes, fetch=(0, 2)) as (vals, valid):
                 for i in range(len(queries)):
                     if ok[i]:
                         got[i].append(vals[i][valid[i]])
@@ -782,7 +822,7 @@ class BatchedServer:
         active = np.arange(qt.shape[1])[None, :] < ql[:, None]
         lanes = (qt.size * int(self.arrays["rank_run_docs"].shape[1]),
                  int(self._lengths_np[qt][active & ok[:, None]].sum()))
-        with step_window(self, step, self.arrays, qt, ql, 0, lanes,
+        with step_window(self, step, self.arrays, qt, ql, 0, (), lanes,
                          fetch=(0, 2)) as (docs, valid):
             empty = np.zeros(0, np.int64)
             return [docs[i][valid[i]].astype(np.int64) if ok[i] else empty
@@ -791,15 +831,17 @@ class BatchedServer:
 
 @contextmanager
 def step_window(server, step, arrays: dict, qt: np.ndarray, ql: np.ndarray,
-                w: int, lanes: tuple[int, int], fetch: tuple[int, ...] = (0, 1)):
-    """Run one call of ``server``'s ``step`` over sweep window ``w`` under
-    an ``engine.window`` span and yield the host copies of its outputs
+                w: int, cursor: tuple, lanes: tuple[int, int],
+                fetch: tuple[int, ...] = (0, 1)):
+    """Run one call of ``server``'s ``step`` over sweep window ``w``, whose
+    position the step's ``cursor`` arguments give, under an
+    ``engine.window`` span and yield the host copies of its outputs
     ``fetch``; the caller's per-query masking runs inside the
     ``engine.fetch`` span.  ``lanes`` is (padded lanes, live lanes)."""
     traces = server.trace_events
     with tracing.span("engine.window", program=step.__name__, window=w,
                       lanes=lanes[0], live_lanes=lanes[1]) as sp:
-        outs = step(arrays, jnp.asarray(qt), jnp.asarray(ql), w * MAX_CAND_ROWS)
+        outs = step(arrays, jnp.asarray(qt), jnp.asarray(ql), *cursor)
         sp.set(traced=int(server.trace_events > traces))
         outs = [outs[i] for i in fetch]
         with tracing.span("engine.device_wait"):

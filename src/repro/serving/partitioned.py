@@ -328,7 +328,7 @@ class PartitionedServer:
                      window_live_lanes(c, self._lane_cum_np, qt[:, 0][ok],
                                        w * MAX_CAND_ROWS))
             with step_window(self, serve, self.pidx.arrays, qt, ql, w,
-                             lanes) as (vals, mask):
+                             (w * MAX_CAND_ROWS,), lanes) as (vals, mask):
                 for qi in range(len(queries)):
                     if ok[qi]:
                         hits[qi].append(vals[:, qi][mask[:, qi]])

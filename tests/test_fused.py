@@ -188,11 +188,51 @@ def test_phrase_probe_at_universe_top(layout, probe):
                   else _kernel_member(interpret=True))
     qt = jnp.asarray([[0, 1]], jnp.int32)
     ql = jnp.asarray([2], jnp.int32)
-    gen = fused_candidates_for if layout == "fused" else candidates_for
-    cand_vals, cand_valid = gen(idx, qt[:, 0], 0)
+    if layout == "fused":  # the whole driving list: entries [lo, hi)
+        cand_vals, cand_valid = fused_candidates_for(
+            idx, idx.c_offsets[qt[:, 0]], idx.c_offsets[qt[:, 0] + 1])
+    else:
+        cand_vals, cand_valid = candidates_for(idx, qt[:, 0], 0)
     match = _probe_terms(idx, qt, ql, cand_vals, cand_valid, 2, phrase=True,
                          member=member)
     got = np.unique(np.asarray(cand_vals)[np.asarray(match)]) - 1
     # 10->11 and (top-3)->(top-2) are real phrase pairs; top->top+1 is out
     # of the universe and must NOT match (sentinel collision would say yes)
     assert np.array_equal(got, np.asarray([10, top - 3])), got
+
+
+# ----------------------------------------------------------------------
+# the fused generator's ragged expansion
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_fused_generator_packs_each_range_from_lane_zero(seed):
+    """Any C-entry range whose postings fit the window comes out as the
+    entries' decoded postings, in order, packed from lane 0 — whatever
+    the entries' lengths — and nothing else is valid."""
+    from repro.serving.engine import fused_candidates_for, window_lanes
+
+    r = np.random.default_rng(seed)
+    idx = build_compressed_anchored(_lists(n_lists=12, drop=0.3))
+    anchors, ptr, ln = (np.asarray(a) for a in (idx.anchors, idx.c_ptr,
+                                                idx.c_len))
+    pool = np.asarray(idx.pool)
+    lanes = window_lanes(idx.max_phrase)
+    n_c = len(anchors)
+    lo = r.integers(0, n_c, size=6)
+    hi = lo.copy()
+    for i in range(len(lo)):  # extend each range while its postings fit
+        while (hi[i] < n_c and r.random() < 0.9
+               and ln[lo[i]:hi[i] + 1].sum() <= lanes):
+            hi[i] += 1
+    lo[0] = hi[0]  # an empty range too
+    vals, valid = fused_candidates_for(idx, jnp.asarray(lo, jnp.int32),
+                                       jnp.asarray(hi, jnp.int32))
+    vals, valid = np.asarray(vals), np.asarray(valid)
+    assert vals.shape == valid.shape == (len(lo), lanes)
+    for i in range(len(lo)):
+        want = [anchors[j] + pool[ptr[j]:ptr[j] + ln[j]]
+                for j in range(lo[i], hi[i])]
+        want = np.concatenate(want) if want else np.zeros(0, np.int64)
+        assert valid[i].sum() == len(want)
+        assert valid[i][:len(want)].all()
+        assert np.array_equal(vals[i][:len(want)], want)

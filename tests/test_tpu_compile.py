@@ -93,9 +93,18 @@ def _fused_index(one_chip, n_docs, anchors, pool, max_phrase, positional):
     return index
 
 
-def _queries(one_chip, batch, width=2):
+def _queries(one_chip, batch, width=2, fused=False):
+    """Query terms, lengths and the window cursor: a scalar ``row_start``
+    (dense), or each query's C-entry range ``row_lo, row_hi`` (fused)."""
     s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    return s((batch, width)), s((batch,)), s(())
+    cursor = (s((batch,)), s((batch,))) if fused else (s(()),)
+    return s((batch, width)), s((batch,)), *cursor
+
+
+#: temporaries of the cell's AND step (batch 16, max_phrase 16,384): the
+#: window of 64 entries padded to max_phrase lanes needed 1.84 GB; the
+#: compacted window of 16 x 16,384 lanes needs far less
+AND_STEP_TEMP_BYTES = 200 * 10**6
 
 
 @pytest.mark.parametrize("name,rows,lanes", [
@@ -135,7 +144,8 @@ def test_kernel_compiles_for_v5e(one_chip, name, rows, lanes):
     ("phrase", False, 0), ("phrase", True, 0)])
 def test_fused_serve_step_fits_v5e(one_chip, mode, doclist, topk):
     """The main path's fused steps at its sizes: the window is
-    (batch*64, max_phrase) int32."""
+    (batch, window_lanes(max_phrase)) int32, max_phrase rounded up to a
+    multiple of 1,024."""
     phrase = mode == "phrase"
     if phrase:
         index = _fused_index(one_chip, MAIN_POS_DOCS, POS_ANCHORS, POS_POOL,
@@ -148,13 +158,17 @@ def test_fused_serve_step_fits_v5e(one_chip, mode, doclist, topk):
     step = make_serve_step(max_terms=2, mode=mode, topk=topk, doclist=doclist,
                            layout="fused", max_phrase=max_phrase,
                            n_docs=float(MAIN_DOCS))
-    _fits(_compile(step, index, *_queries(one_chip, BATCH)))
+    compiled = _compile(step, index, *_queries(one_chip, BATCH, fused=True))
+    _fits(compiled)
+    if mode == "and" and not doclist and not topk and BATCH == 16:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < AND_STEP_TEMP_BYTES, temp
 
 
 def test_named_and_step_compiles_for_v5e_at_the_cell_shape(one_chip):
     """The server's own AND step, under its program name and scopes, at
     the benchmark cell's window: a batch of 16 over a max_phrase of
-    16,384."""
+    16,384, so 16 x 16,384 lanes."""
     import numpy as np
 
     from repro.serving.engine import AND, BatchedServer
@@ -165,11 +179,13 @@ def test_named_and_step_compiles_for_v5e_at_the_cell_shape(one_chip):
                            _lane_cum_np=np.zeros(1))
     index = _fused_index(one_chip, MAIN_DOCS, NP_ANCHORS, NP_POOL,
                          NP_MAX_PHRASE, positional=False)
-    compiled = server._step(AND, 2).lower(index, *_queries(one_chip, 16)).compile()
+    compiled = server._step(AND, 2).lower(
+        index, *_queries(one_chip, 16, fused=True)).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_serve_and")
     assert "anchor_search" in text and "pool_search" in text
     _fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < AND_STEP_TEMP_BYTES
 
 
 def test_kernel_serve_step_compiles_for_v5e(one_chip, monkeypatch):
@@ -183,7 +199,8 @@ def test_kernel_serve_step_compiles_for_v5e(one_chip, monkeypatch):
                          KERNEL_DOCS, positional=False)
     step = make_serve_step(max_terms=2, mode="and", layout="fused",
                            probe="kernel", max_phrase=KERNEL_DOCS)
-    compiled = _compile(step, index, *_queries(one_chip, KERNEL_BATCH))
+    compiled = _compile(step, index,
+                        *_queries(one_chip, KERNEL_BATCH, fused=True))
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
 

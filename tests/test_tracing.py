@@ -19,18 +19,22 @@ from repro.serving.engine import (
     PHRASE,
     RANK,
     BatchedServer,
+    window_lanes,
 )
 from repro.serving.frontend import FrontendConfig, MicroBatchFrontend
 from repro.serving.session import Session
 
+from lane_budget import lane_budget_windows
 
-def _docs():
-    """700 documents: ``common`` in each, ``often`` and ``some`` in a
-    random 60% and 30% (lists of over 64 C entries, so a sweep takes two
-    windows), ``rare`` in the first 10, and runs the grammar compresses."""
+
+def _docs(n: int = 700):
+    """``n`` documents: ``common`` in each, ``often`` and ``some`` in a
+    random 60% and 30% (lists of over 64 C entries, so a dense sweep takes
+    two windows), ``rare`` in the first 10, and runs the grammar
+    compresses."""
     rng = np.random.default_rng(7)
     docs = []
-    for d in range(700):
+    for d in range(n):
         words = ["common"]
         words += ["often"] if rng.random() < 0.6 else []
         words += ["some"] if rng.random() < 0.3 else []
@@ -103,10 +107,12 @@ def test_a_micro_batch_leaves_one_span_tree(session):
         assert req.attrs == {"request": q.attrs["request"], "batch": bid}
         assert req.start == q.start and q.end <= batch.start <= req.end
 
-    # one window span per window the sweep needed
+    # one window span per window the sweep needed: the fused layout's lane
+    # budget, max_phrase rounded up, holds each of these lists whole
     server = session.server
+    assert server.layout == "fused"
     qt, _, ok = server.encode([q.split() for q in QUERIES], sort_by_length=True)
-    assert len(windows) == server._n_windows(qt, ok) == 2
+    assert len(windows) == server._n_windows(qt, ok) == 1
 
     # the frontend's latency samples are the request spans
     lat = fe.metrics()["latency"]
@@ -117,8 +123,18 @@ def test_a_micro_batch_leaves_one_span_tree(session):
         assert np.array_equal(a, session.execute(q))
 
 
-@pytest.mark.parametrize("layout", ["fused", "dense"])
-def test_live_lanes_match_a_brute_count(index, layout):
+@pytest.fixture(scope="module")
+def postings_index():
+    """2,200 documents with no grammar: every C entry holds one posting,
+    so ``often``'s list outgrows one fused window of 1,024 lanes."""
+    return NonPositionalIndex.build(_docs(2200), store="repair_skip",
+                                    max_rules=0)
+
+
+@pytest.mark.parametrize("layout", ["fused", "dense", "fused-postings"])
+def test_live_lanes_match_a_brute_count(index, postings_index, layout):
+    if layout == "fused-postings":
+        index, layout = postings_index, "fused"
     server = BatchedServer.from_index(index, layout=layout, expand_len=8)
     queries = [q.split() for q in QUERIES] + [["nosuchword", "common"]]
     t0 = time.perf_counter()
@@ -127,23 +143,34 @@ def test_live_lanes_match_a_brute_count(index, layout):
                if r.name == "engine.window"]
     qt, _, ok = server.encode(queries, sort_by_length=True)
     assert not ok[-1]
-    per_row = (server.max_phrase if layout == "fused"
-               else server.arrays["expand"].shape[-1])
+    assert len(windows) == server._n_windows(qt, ok)
     c_off = np.asarray(server.arrays["c_offsets"])
     row_len = (np.asarray(server.arrays["c_len"]) if layout == "fused"
                else np.asarray(server.arrays["expand_valid"]).sum(axis=1))
-    for w in windows:
-        assert w.attrs["lanes"] == len(queries) * MAX_CAND_ROWS * per_row
-        live = 0
-        for first in qt[ok, 0]:
+    # the rows each window swept, over the active queries' driving lists
+    swept = [[] for _ in windows]
+    for first in qt[ok, 0]:
+        if layout == "fused":
+            for w, rows in enumerate(lane_budget_windows(server, first)):
+                swept[w] += rows
+        else:
             for row in range(c_off[first], c_off[first + 1]):
-                if (row - c_off[first]) // MAX_CAND_ROWS == w.attrs["window"]:
-                    live += int(row_len[row])
+                swept[(row - c_off[first]) // MAX_CAND_ROWS].append(row)
+    for w in windows:
+        if layout == "fused":
+            assert w.attrs["lanes"] == len(queries) * window_lanes(
+                server.max_phrase)
+        else:
+            assert w.attrs["lanes"] == (len(queries) * MAX_CAND_ROWS
+                                        * server.arrays["expand"].shape[-1])
+        live = sum(int(row_len[row]) for row in swept[w.attrs["window"]])
         assert w.attrs["live_lanes"] == live
     # over all windows, every posting of each active query's rarest list
     rarest = [min(len(index.query_word(t)) for t in q)
               for q, o in zip(queries, ok) if o]
     assert sum(w.attrs["live_lanes"] for w in windows) == sum(rarest)
+    if index is postings_index:
+        assert len(windows) == 2
 
 
 def test_the_ring_is_bounded_and_says_when_it_dropped(monkeypatch):
@@ -216,9 +243,10 @@ def test_device_programs_are_named_after_what_they_serve(
     server = servers[PHRASE if kind == PHRASE else AND]
     qt = np.zeros((4, 2), np.int32)
     ql = np.full(4, 2, np.int32)
+    cursor, _ = next(server._windows(qt, np.ones(4, bool)))
     traces = server.trace_count
     lowered = server._step(kind, 2, topk=topk, doclist=doclist).lower(
-        server.arrays, qt, ql, 0)
+        server.arrays, qt, ql, *cursor)
     assert f"module @jit_{name} " in lowered.as_text()
     assert server.trace_count == traces + 1  # the retrace counter still counts
 
@@ -228,7 +256,9 @@ def test_the_and_step_carries_its_scopes(session):
     assert server.layout == "fused"
     qt = np.zeros((4, 2), np.int32)
     ql = np.full(4, 2, np.int32)
-    hlo = server._step(AND, 2).lower(server.arrays, qt, ql, 0).compile().as_text()
+    cursor, _ = next(server._windows(qt, np.ones(4, bool)))
+    hlo = server._step(AND, 2).lower(server.arrays, qt, ql,
+                                     *cursor).compile().as_text()
     assert hlo.startswith("HloModule jit_serve_and")
     # op names read "jit(serve_and)/probe/vmap(anchor_search)/while/..."
     scopes = {part.split("(")[-1].rstrip(")")
