@@ -6,8 +6,9 @@ largest reading of sound runs and the smallest reading printed here.
 
     python3 bench/control.py --workload wiki-archive.and-low --seeds 1,2,3
 
-The control cuts each AND query's driving list (its shorter list) to its
-first ``reference.CONTROL_CAP`` postings, as a sweep with a fixed candidate
+The control cuts each AND query's driving list (its shorter list), or each
+phrase's rarest word's position list, to its first
+``reference.CONTROL_CAP`` entries, as a sweep with a fixed candidate
 budget would answer; the reading is the count of answers that differ.  The
 queries are the closed loop's first ``--rounds`` rounds, about what one run
 answers.  The benchmark's own runs never run the control.
@@ -22,17 +23,18 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from bench.collection import generate_docs  # noqa: E402
-from bench.harness import check, load_cell  # noqa: E402
-from bench.reference import WordReference  # noqa: E402
+from bench.harness import check, index_kind, load_cell  # noqa: E402
+from bench.reference import parse  # noqa: E402
 from bench.traffic import make_traffic  # noqa: E402
 
 
 def readings(cell, seed: int, rounds: int) -> dict:
-    wref = WordReference(generate_docs(cell.config["collection"], seed))
-    streams = make_traffic(cell.traffic, wref, seed).streams
+    ref = index_kind(cell.config).reference(
+        generate_docs(cell.config["collection"], seed))
+    streams = make_traffic(cell.traffic, ref, seed).streams
     queries = [q for r in range(rounds) for s in streams for q in s[r:r + 1]]
-    checks = check([(q, None) for q in queries], wref, control=True)
-    lens = sorted(min(wref.df(w) for w in q.split()) for q in queries)
+    checks = check([(q, None) for q in queries], ref, control=True)
+    lens = sorted(min(ref.df(w) for w in parse(q)) for q in queries)
     return {"seed": seed, "queries": len(queries),
             "driving_df_median": lens[len(lens) // 2], "driving_df_max": lens[-1],
             **{k: v["value"] for k, v in checks.items()}}

@@ -5,8 +5,13 @@ A cell is found by name in ``BENCHMARK.json``: its configuration file, its
 traffic file ``bench/traffic/<mix>.json`` and one reader per metric,
 ``bench/metrics/<metric>.py``, whose ``read(run)`` returns the metric's
 value from the :class:`Run` record, or ``None`` where it finds nothing to
-read.  Adding a cell, a configuration or a metric adds files and entries;
-it edits none.
+read.  A cell reads every metric that has no ``workloads`` list and each
+one whose list names it.  The configuration's ``index.kind`` picks the
+index the program builds and the reference that checks it
+(:data:`INDEX_KINDS`: ``nonpositional`` or ``positional``); the traffic
+file's ``kind`` picks the queries (``bench/traffic.py``: ``and`` or
+``phrase``).  Adding a cell, a configuration or a metric adds files and
+entries; it edits none.
 
 The program receives only the generated documents and query strings.  The
 window drives ``MicroBatchFrontend.submit`` -> ``Session.execute`` -> the
@@ -26,12 +31,13 @@ import sys
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from typing import NamedTuple
 
 import numpy as np
 
 from . import reference, roofline
 from .collection import generate_docs
-from .reference import WordReference, parse
+from .reference import PositionalReference, WordReference, parse
 from .trace import (EXECUTE_SPAN, PENDING_SPAN, WINDOW_SPAN, breakdown,
                     find_xplane, reduce_file)
 from .traffic import Traffic, make_traffic
@@ -40,6 +46,29 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE = BENCH / ".cache"
 sys.path.insert(0, str(ROOT / "src"))
+
+from repro.core.index import NonPositionalIndex, PositionalIndex  # noqa: E402
+
+
+class IndexKind(NamedTuple):
+    index_class: type  # the program's index class
+    reference: type  # the reference that checks its answers
+
+
+#: ``index.kind`` of a configuration -> what the harness builds and checks
+INDEX_KINDS = {
+    "nonpositional": IndexKind(NonPositionalIndex, WordReference),
+    "positional": IndexKind(PositionalIndex, PositionalReference),
+}
+
+
+def index_kind(config: dict) -> IndexKind:
+    kind = config["index"]["kind"]
+    if kind not in INDEX_KINDS:
+        raise ValueError(f"unknown index kind {kind!r} in configuration "
+                         f"{config['name']!r}; the harness knows "
+                         f"{sorted(INDEX_KINDS)}")
+    return INDEX_KINDS[kind]
 
 
 @dataclass
@@ -50,6 +79,7 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     chips: int = 1
+    root: Path = ROOT  # where its files, metric readers among them, lie
 
 
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
@@ -68,8 +98,10 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
                      else m["moves"] in names)]
     return Cell(name=workload,
                 config=json.loads((root / entry["file"]).read_text()),
-                traffic=json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text()),
-                end_to_end=e2e, per_layer=per_layer, chips=int(w["chips"]))
+                traffic=json.loads((root / BENCH.relative_to(ROOT) / "traffic"
+                                    / f"{w['traffic']}.json").read_text()),
+                end_to_end=e2e, per_layer=per_layer, chips=int(w["chips"]),
+                root=root)
 
 
 @dataclass
@@ -122,25 +154,26 @@ def build_index(config: dict, seed: int, cache: Path):
     """(documents, reference, session) of a configuration for one seed.
 
     Every run does the same work: generate the documents, ingest them (build
-    the index and persist it with the program's own format, over whatever an
-    earlier run left at the same path) and open the persisted index, as a
-    deployment would.  Nothing is reused from an earlier run, so set-up
-    depends on the code under test and the seed alone."""
+    the index of the configuration's ``index.kind`` and persist it with the
+    program's own format, over whatever an earlier run left at the same
+    path) and open the persisted index, as a deployment would.  Nothing is
+    reused from an earlier run, so set-up depends on the code under test
+    and the seed alone."""
     from repro.core.artifact import save_index
-    from repro.core.index import NonPositionalIndex
     from repro.serving.session import Session
 
+    kind = index_kind(config)
     t = time.perf_counter()
     docs = generate_docs(config["collection"], seed)
     log(f"  generated {len(docs)} documents in {time.perf_counter() - t:.2f} s")
     t = time.perf_counter()
-    wref = WordReference(docs)
-    log(f"  counted the reference's document frequencies in "
+    ref = kind.reference(docs)
+    log(f"  built the {kind.reference.__name__} in "
         f"{time.perf_counter() - t:.2f} s")
     t = time.perf_counter()
-    ix = NonPositionalIndex.build(docs, store=config["index"]["store"])
-    log(f"  built the {config['index']['store']} index in "
-        f"{time.perf_counter() - t:.2f} s")
+    ix = kind.index_class.build(docs, store=config["index"]["store"])
+    log(f"  built the {config['index']['store']} "
+        f"{kind.index_class.__name__} in {time.perf_counter() - t:.2f} s")
     art = cache / "index" / config["name"]
     shutil.rmtree(art, ignore_errors=True)
     t = time.perf_counter()
@@ -148,11 +181,15 @@ def build_index(config: dict, seed: int, cache: Path):
     del ix
     session = Session.open(art)
     log(f"  persisted and opened the index in {time.perf_counter() - t:.2f} s")
-    return docs, wref, session
+    return docs, ref, session
 
 
 def server_bytes(session) -> int:
-    return sum(int(a.nbytes) for a in session.server.arrays.values())
+    """Bytes of the arrays of every device server the session holds, each
+    array counted once."""
+    arrays = {id(a): a for s in (session.server, session.positional_server)
+              if s is not None for a in s.arrays.values()}
+    return sum(int(a.nbytes) for a in arrays.values())
 
 
 # ----------------------------------------------------------------------
@@ -269,16 +306,17 @@ def drive(session, cfg, traffic: Traffic, seconds: float, run: Run,
 # ----------------------------------------------------------------------
 # correctness: every answer the window returned, against the reference
 # ----------------------------------------------------------------------
-def check(answers, wref: WordReference, control: bool = False) -> dict:
-    """The numbers compared, each with its limit.  ``control`` puts the
-    reference's control variant in the program's place (``control.py``)."""
-    cap = reference.CONTROL_CAP if control else None
+def check(answers, ref, control: bool = False) -> dict:
+    """The numbers compared, each with its limit: every answer against
+    ``ref.answer``, the reference of the cell's index kind.  ``control``
+    puts the reference's control variant in the program's place
+    (``control.py``)."""
     wrong = 0
     for q, ans in answers:
         terms = parse(q)
-        want = wref.conjunctive(terms)
+        want = ref.answer(terms)
         if control:
-            ans = wref.conjunctive(terms, cap=cap)
+            ans = ref.answer(terms, cap=reference.CONTROL_CAP)
         wrong += not np.array_equal(np.asarray(ans, np.int64), want)
     return {"wrong_answers": {"value": wrong, "limit": 0}}
 
@@ -286,8 +324,8 @@ def check(answers, wref: WordReference, control: bool = False) -> dict:
 # ----------------------------------------------------------------------
 # metrics
 # ----------------------------------------------------------------------
-def read_metric(name: str, run: Run):
-    path = BENCH / "metrics" / f"{name}.py"
+def read_metric(name: str, run: Run, root: Path = ROOT):
+    path = root / BENCH.relative_to(ROOT) / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -312,9 +350,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     config, spec = cell.config, cell.traffic
     run = Run()
 
-    docs, wref, session = build_index(config, seed, cache)
+    docs, ref, session = build_index(config, seed, cache)
     run.text_bytes = sum(len(d) for d in docs)
-    traffic = make_traffic(spec, wref, seed)
+    traffic = make_traffic(spec, ref, seed)
     cfg = FrontendConfig(max_batch=int(config["frontend"]["max_batch"]))
     t = time.perf_counter()
     asyncio.run(_warm(session, cfg, traffic.warmup))
@@ -345,14 +383,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     memory_peak = peak_bytes()
     if trace:
         run.peaks = roofline.peaks(info["kind"])
+        served = session.index if session.index is not None else session.positional
         run.step_bytes = roofline.sweep_bytes(
-            session.index, [parse(q) for q, _ in run.answers],
+            served, [parse(q) for q, _ in run.answers],
             [len(a) for _, a in run.answers])
     del session
     gc.collect()
 
     t = time.perf_counter()
-    checks = check(run.answers, wref)
+    checks = check(run.answers, ref)
     if run.errors:
         log(f"  {len(run.errors)} request(s) failed with an error; the first: "
             f"{run.errors[0]}")
@@ -374,7 +413,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         device.update(busy_s=run.trace.busy_s, window_s=run.trace.window_s)
     metrics = {}
     for m in cell.per_layer if trace else cell.end_to_end:
-        value = read_metric(m["name"], run)
+        value = read_metric(m["name"], run, cell.root)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     result.update(metrics=metrics, device=device)

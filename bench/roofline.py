@@ -32,9 +32,10 @@ def list_bytes(store, term_id: int) -> int:
 
 
 def sweep_bytes(index, queries: list[list[str]], answer_lens: list[int]) -> int:
-    """The AND step: every query term's list read once, every answer
-    written once (int32 per hit).  ``index`` is the host index
-    whose ``lookup`` maps a query term to its list."""
+    """The sweep step, AND or phrase: every query term's list read once,
+    every answer written once (int32 per hit).  ``index`` is the host index
+    that served the queries, whose ``lookup`` maps a query term to its
+    list; ``queries`` holds each query's terms as parsed for its kind."""
     total = INT32 * int(sum(answer_lens))
     for terms in queries:
         for t in terms:
