@@ -280,7 +280,7 @@ def kernel_phase(docs: int, per_kind: int, seed: int) -> dict:
         check(session.device_batches > 0, f"{layout}: no device batch ran")
         srv = session.server
         qt, ql, _ = srv.encode([list(parse_query(queries[0]).terms)], width=2)
-        cursor, _ = next(srv._windows(qt, np.ones(len(qt), bool)))
+        cursor, _ = next(srv._windows(qt, ql, np.ones(len(qt), bool)))
         text = srv._step(AND, qt.shape[1]).lower(
             srv.arrays, jnp.asarray(qt), jnp.asarray(ql), *cursor).as_text()
         found[layout] = "tpu_custom_call" in text

@@ -202,89 +202,85 @@ def build_compressed_anchored(lists: list[np.ndarray], **kw) -> CompressedAnchor
 # ----------------------------------------------------------------------
 # batched membership / intersection (jit-able)
 # ----------------------------------------------------------------------
-def member_batch(idx: AnchoredIndex, list_ids: jax.Array, values: jax.Array) -> jax.Array:
+#: no int32 slice needs more halvings than this
+MAX_SEARCH_STEPS = 32
+#: (anchor, pool) halvings that converge over any slice: the default
+FULL_DEPTH = (MAX_SEARCH_STEPS, MAX_SEARCH_STEPS)
+
+
+def lower_bound(keys: jax.Array, lo: jax.Array, hi: jax.Array,
+                targets: jax.Array, steps: jax.Array | int) -> jax.Array:
+    """Lane by lane, the first index in ``[lo, hi)`` whose key is at least
+    the lane's target (``hi`` where none is): ``steps`` halvings of each
+    range, at most MAX_SEARCH_STEPS.  A lane freezes once its range is
+    empty, so a search over n entries has converged after
+    ``n.bit_length()`` halvings and more change nothing; a lane with a
+    longer range stops short, inside it.  ``steps`` may be traced: the
+    loop then runs as many halvings as the data needs under one compiled
+    program."""
+
+    def body(_, lh):
+        l, h = lh
+        mid = (l + h) // 2
+        active = l < h
+        go_right = active & (keys[mid] < targets)
+        return (jnp.where(go_right, mid + 1, l),
+                jnp.where(active & ~go_right, mid, h))
+
+    steps = jnp.minimum(jnp.asarray(steps, jnp.int32), MAX_SEARCH_STEPS)
+    l, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
+    return l
+
+
+def member_batch(idx: AnchoredIndex, list_ids: jax.Array, values: jax.Array,
+                 steps=FULL_DEPTH) -> jax.Array:
     """For each (list_id, value) pair: is value in that list?  Fully batched.
 
     values are absolute postings; comparison in cumulative-gap space (+1).
     Anchors are per-list cumulative sums, so the binary search runs within
-    the list's [lo, hi) slice — a fixed-depth ``fori_loop`` (vectorizes under
-    vmap; the Pallas ``anchor_intersect`` kernel is the tiled-compare TPU
-    variant of the same probe).
+    the list's [lo, hi) slice (the Pallas ``anchor_intersect`` kernel is the
+    tiled-compare TPU variant of the same probe).  ``steps[0]`` is the
+    halvings it runs: the answer is exact for every lane whose list has
+    fewer than ``2 ** steps[0]`` C entries, so the default answers them all.
     """
     targets = values.astype(jnp.int32) + 1
     lo = idx.c_offsets[list_ids]
     hi = idx.c_offsets[list_ids + 1]
-
-    def one(lid_lo, lid_hi, t):
-        # find first entry in [lo, hi) whose anchor >= t, then step back:
-        # entry j covers targets in (anchor[j], anchor[j] + phrase_sum]
-        def body(_, lh):
-            l, h = lh
-            mid = (l + h) // 2
-            active = l < h  # fixed-depth loop: freeze once converged
-            go_right = active & (idx.anchors[mid] < t)
-            new_l = jnp.where(go_right, mid + 1, l)
-            new_h = jnp.where(active & ~go_right, mid, h)
-            return (new_l, new_h)
-
-        with jax.named_scope("anchor_search"):
-            l, _ = jax.lax.fori_loop(0, 32, body, (lid_lo, lid_hi))
-        j = jnp.maximum(l - 1, lid_lo)
-        row = idx.expand[j]
-        ok = idx.expand_valid[j] & (row == t)
-        return ok.any() & (lid_lo < lid_hi)
-
-    return jax.vmap(one)(lo, hi, targets)
+    # find first entry in [lo, hi) whose anchor >= t, then step back:
+    # entry j covers targets in (anchor[j], anchor[j] + phrase_sum]
+    with jax.named_scope("anchor_search"):
+        l = lower_bound(idx.anchors, lo, hi, targets, steps[0])
+    j = jnp.maximum(l - 1, lo)
+    ok = idx.expand_valid[j] & (idx.expand[j] == targets[:, None])
+    return ok.any(axis=1) & (lo < hi)
 
 
 def member_batch_compressed(
-    idx: CompressedAnchoredIndex, list_ids: jax.Array, values: jax.Array
+    idx: CompressedAnchoredIndex, list_ids: jax.Array, values: jax.Array,
+    steps=FULL_DEPTH,
 ) -> jax.Array:
     """Fused-layout membership: binary-search the anchors exactly as
     :func:`member_batch`, then — because the covering entry's pool row is
-    prefix-summed, hence strictly increasing — a second fixed-depth binary
-    search *inside* the row.  Membership touches ``log2(max_phrase)`` pool
-    lanes instead of reading a ``max_phrase``-wide expand row, the decoded
-    postings never materialize anywhere."""
+    prefix-summed, hence strictly increasing — a second binary search
+    *inside* the row, so the decoded postings never materialize anywhere.
+    ``steps`` is the (anchor, pool) halvings: the answer is exact for every
+    lane whose list has fewer than ``2 ** steps[0]`` C entries, none of
+    more than ``2 ** steps[1] - 1`` postings."""
     if int(idx.anchors.shape[0]) == 0:
         return jnp.zeros(values.shape, dtype=bool)
     targets = values.astype(jnp.int32) + 1
     lo = idx.c_offsets[list_ids]
     hi = idx.c_offsets[list_ids + 1]
+    with jax.named_scope("anchor_search"):
+        l = lower_bound(idx.anchors, lo, hi, targets, steps[0])
+    j = jnp.maximum(l - 1, lo)
+    # membership of t in entry j == membership of t - anchors[j] in its
+    # sorted prefix-sum row [c_ptr[j], c_ptr[j] + c_len[j])
+    tt = targets - idx.anchors[j]
+    p_lo = idx.c_ptr[j]
+    p_hi = p_lo + idx.c_len[j]
+    with jax.named_scope("pool_search"):
+        l2 = lower_bound(idx.pool, p_lo, p_hi, tt, steps[1])
     pool_top = int(idx.pool.shape[0]) - 1
-    depth = max(int(idx.max_phrase), 1).bit_length() + 1
-
-    def one(lid_lo, lid_hi, t):
-        def body(_, lh):
-            l, h = lh
-            mid = (l + h) // 2
-            active = l < h
-            go_right = active & (idx.anchors[mid] < t)
-            new_l = jnp.where(go_right, mid + 1, l)
-            new_h = jnp.where(active & ~go_right, mid, h)
-            return (new_l, new_h)
-
-        with jax.named_scope("anchor_search"):
-            l, _ = jax.lax.fori_loop(0, 32, body, (lid_lo, lid_hi))
-        j = jnp.maximum(l - 1, lid_lo)
-        # membership of t in entry j == membership of t - anchors[j] in its
-        # sorted prefix-sum row [c_ptr[j], c_ptr[j] + c_len[j])
-        tt = t - idx.anchors[j]
-        p_lo = idx.c_ptr[j]
-        p_hi = p_lo + idx.c_len[j]
-
-        def body2(_, lh):
-            l2, h2 = lh
-            mid = (l2 + h2) // 2
-            active = l2 < h2
-            go_right = active & (idx.pool[mid] < tt)
-            new_l = jnp.where(go_right, mid + 1, l2)
-            new_h = jnp.where(active & ~go_right, mid, h2)
-            return (new_l, new_h)
-
-        with jax.named_scope("pool_search"):
-            l2, _ = jax.lax.fori_loop(0, depth, body2, (p_lo, p_hi))
-        hit = (l2 < p_hi) & (idx.pool[jnp.minimum(l2, pool_top)] == tt)
-        return hit & (lid_lo < lid_hi)
-
-    return jax.vmap(one)(lo, hi, targets)
+    hit = (l2 < p_hi) & (idx.pool[jnp.minimum(l2, pool_top)] == tt)
+    return hit & (lo < hi)

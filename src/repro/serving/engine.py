@@ -31,12 +31,15 @@ from __future__ import annotations
 import warnings
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.anchors import (
+    FULL_DEPTH,
+    MAX_SEARCH_STEPS,
     AnchoredIndex,
     CompressedAnchoredIndex,
     build_anchored,
@@ -292,14 +295,16 @@ def fused_candidates_for(idx: CompressedAnchoredIndex, row_lo: jax.Array,
 
 
 def _probe_terms(idx, query_terms, query_lens, cand_vals, cand_valid,
-                 max_terms: int, phrase: bool, member=None):
+                 max_terms: int, phrase: bool, member=None, steps=FULL_DEPTH):
     """AND / phrase probe loop shared by all steps.  For phrase queries term
     ``t`` probes candidate + t (offset-shifted intersection, §3).  ``member``
-    swaps the probe implementation (vmapped binary search by default —
-    picked by index layout — or the Pallas kernels via ``probe="kernel"``)."""
+    swaps the probe implementation: by default the binary searches of the
+    index's layout, which run ``steps``, the (anchor, pool) halvings of
+    :func:`search_steps`; or the Pallas kernels via ``probe="kernel"``."""
     if member is None:
-        member = (member_batch_compressed
-                  if isinstance(idx, CompressedAnchoredIndex) else member_batch)
+        member = partial(member_batch_compressed
+                         if isinstance(idx, CompressedAnchoredIndex)
+                         else member_batch, steps=steps)
     b, nc = cand_vals.shape
     match = cand_valid
     for t in range(1, max_terms):
@@ -407,13 +412,17 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
 
     ``mode`` is "and" (conjunctive doc queries) or "phrase" (offset-shifted
     positional probes).  The step is called as ``serve(index, query_terms,
-    query_lens, *cursor)``, where the cursor names the sweep window: on
-    the dense layout ``row_start``, the window's first C entry within
-    every driving list (default 0); on the fused layout ``row_lo,
+    query_lens, *cursor, steps)``, where the cursor names the sweep
+    window: on the dense layout ``row_start``, the window's first C entry
+    within every driving list (default 0); on the fused layout ``row_lo,
     row_hi``, each query's absolute C-entry range, int32[B] (see
-    :func:`fused_candidates_for`).  With ``topk == 0`` the step returns
-    ``(candidate postings (B, C), match mask (B, C))`` for the window;
-    with ``topk == k`` it additionally ranks on device and returns
+    :func:`fused_candidates_for`).  ``steps``, int32[2], is the halvings
+    of the anchor and pool searches, as :func:`search_steps` reckons them
+    for the window (by default FULL_DEPTH, which suits any window); it is
+    traced, so a batch that needs other counts reuses the compiled step.
+    With ``topk == 0`` the step returns ``(candidate postings (B, C), match
+    mask (B, C))`` for the window; with ``topk == k`` it additionally
+    ranks on device and returns
     ``(top postings (B, k), top scores (B, k), top valid (B, k))``.
     With ``doclist=True`` the step returns ``(doc ids (B, C), keep (B, C))``:
     matching positions map to documents through the ``doc_starts`` array in
@@ -444,7 +453,9 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
                   else _kernel_member(interpret=interpret))
 
     def serve(index: dict, query_terms: jax.Array, query_lens: jax.Array,
-              *cursor: jax.Array | int):
+              *window: jax.Array | int):
+        n = 2 if fused else 1  # the cursor's arguments; the bounds follow
+        cursor, steps = window[:n], (window[n:] or (FULL_DEPTH,))[0]
         with jax.named_scope("candidates"):
             if fused:
                 idx = _as_compressed(index, max_phrase)
@@ -455,7 +466,8 @@ def make_serve_step(max_terms: int = 8, mode: str = AND, topk: int = 0,
                                                        *cursor)
         with jax.named_scope("probe"):
             match = _probe_terms(idx, query_terms, query_lens, cand_vals,
-                                 cand_valid, max_terms, phrase, member=member)
+                                 cand_valid, max_terms, phrase, member=member,
+                                 steps=steps)
         if doclist:
             with jax.named_scope("doclist_dedup"):
                 vals = cand_vals - 1
@@ -558,6 +570,7 @@ class BatchedServer:
     _lengths_np: np.ndarray | None = None
     _c_offsets_np: np.ndarray | None = None
     _lane_cum_np: np.ndarray | None = None
+    _longest_np: np.ndarray | None = None  # fused: each list's longest entry
 
     def __post_init__(self):
         if self._lengths_np is None:
@@ -566,6 +579,9 @@ class BatchedServer:
             self._c_offsets_np = np.asarray(self.arrays["c_offsets"])
         if self._lane_cum_np is None:
             self._lane_cum_np = lane_cumsum(self.arrays)
+        if self._longest_np is None and self.layout == "fused":
+            self._longest_np = longest_entry(self._c_offsets_np,
+                                             self._lane_cum_np)
 
     #: posting-layout array names (device-memory accounting; rank_* and
     #: doc_starts are layout-independent extras)
@@ -693,10 +709,11 @@ class BatchedServer:
             self._steps[key] = jax.jit(counted)
         return self._steps[key]
 
-    def _windows(self, qt: np.ndarray, ok: np.ndarray):
+    def _windows(self, qt: np.ndarray, ql: np.ndarray, ok: np.ndarray):
         """The sweep's windows over each query's driving list ``qt[:, 0]``,
-        at least one: ``(cursor, (padded lanes, live lanes))`` per step
-        call, from host copies only.
+        at least one: ``(cursor, counts)`` per step call, from host copies
+        only.  The cursor ends with the window's search bounds (see
+        :meth:`_bounds`); ``counts`` are its ``engine.window`` attrs.
 
         Dense layout: MAX_CAND_ROWS expand rows of every list per window.
         Fused layout: each query takes the C entries, from where its last
@@ -710,9 +727,13 @@ class BatchedServer:
             drive = first[ok] if ok.any() else first[:1]
             rows = int((c_off[drive + 1] - c_off[drive]).max())
             for w in range(max(1, -(-rows // MAX_CAND_ROWS))):
-                live = window_live_lanes(c_off, cum, first[ok],
-                                         w * MAX_CAND_ROWS)
-                yield (w * MAX_CAND_ROWS,), (b * MAX_CAND_ROWS * per_row, live)
+                start = w * MAX_CAND_ROWS
+                steps, counts = self._bounds(
+                    qt, ql, ok & (c_off[first + 1] - c_off[first] > start))
+                yield (start, steps), dict(
+                    lanes=b * MAX_CAND_ROWS * per_row,
+                    live_lanes=window_live_lanes(c_off, cum, first[ok], start),
+                    **counts)
             return
         lanes = window_lanes(self.max_phrase)
         lo = np.where(ok, c_off[first], 0).astype(np.int64)
@@ -721,15 +742,27 @@ class BatchedServer:
             # the furthest entry whose postings since lo fit the lanes
             reach = np.searchsorted(cum, cum[lo] + lanes, side="right") - 1
             end = np.minimum(np.minimum(reach, hi), lo + lanes)
-            yield ((lo.astype(np.int32), end.astype(np.int32)),
-                   (b * lanes, int((cum[end] - cum[lo]).sum())))
+            steps, counts = self._bounds(qt, ql, end > lo)
+            yield (lo.astype(np.int32), end.astype(np.int32), steps), dict(
+                lanes=b * lanes, live_lanes=int((cum[end] - cum[lo]).sum()),
+                **counts)
             lo = end
             if not (lo < hi).any():
                 return
 
-    def _n_windows(self, qt: np.ndarray, ok: np.ndarray) -> int:
+    def _bounds(self, qt: np.ndarray, ql: np.ndarray,
+                live: np.ndarray) -> tuple[np.ndarray, dict]:
+        """A window's search bounds, for the step (:func:`search_steps` over
+        the queries ``live`` in it), and as the ``anchor_steps`` and
+        ``pool_steps`` its span records.  The Pallas probe runs no
+        halvings: its span records none."""
+        steps = search_steps(self._c_offsets_np, self._longest_np, qt, ql, live)
+        n = steps if self.probe != "kernel" else np.zeros_like(steps)
+        return steps, dict(anchor_steps=int(n[0]), pool_steps=int(n[1]))
+
+    def _n_windows(self, qt: np.ndarray, ql: np.ndarray, ok: np.ndarray) -> int:
         """Step calls a sweep over the batch makes (topk may stop sooner)."""
-        return sum(1 for _ in self._windows(qt, ok))
+        return sum(1 for _ in self._windows(qt, ql, ok))
 
     def _sweep(self, kind: str, queries: list[list[str]],
                width: int | None = None) -> list[np.ndarray]:
@@ -737,9 +770,9 @@ class BatchedServer:
                                  width=width)
         step = self._step(kind, qt.shape[1])
         hits: list[list[np.ndarray]] = [[] for _ in queries]
-        for w, (cursor, lanes) in enumerate(self._windows(qt, ok)):
+        for w, (cursor, counts) in enumerate(self._windows(qt, ql, ok)):
             with step_window(self, step, self.arrays, qt, ql, w, cursor,
-                             lanes) as (vals, mask):
+                             counts) as (vals, mask):
                 for i in range(len(queries)):
                     if ok[i]:
                         hits[i].append(vals[i][mask[i]])
@@ -769,9 +802,9 @@ class BatchedServer:
         qt, ql, ok = self.encode(queries, sort_by_length=not phrase, width=width)
         step = self._step(kind, qt.shape[1], doclist=True)
         hits: list[list[np.ndarray]] = [[] for _ in queries]
-        for w, (cursor, lanes) in enumerate(self._windows(qt, ok)):
+        for w, (cursor, counts) in enumerate(self._windows(qt, ql, ok)):
             with step_window(self, step, self.arrays, qt, ql, w, cursor,
-                             lanes) as (docs, keep):
+                             counts) as (docs, keep):
                 for i in range(len(queries)):
                     if ok[i]:
                         hits[i].append(docs[i][keep[i]])
@@ -786,9 +819,9 @@ class BatchedServer:
         step = self._step(AND, qt.shape[1], topk=int(k))
         got: list[list[np.ndarray]] = [[] for _ in queries]
         counts = np.zeros(len(queries), np.int64)
-        for w, (cursor, lanes) in enumerate(self._windows(qt, ok)):
-            with step_window(self, step, self.arrays, qt, ql, w, cursor,
-                             lanes, fetch=(0, 2)) as (vals, valid):
+        for w, (cursor, attrs) in enumerate(self._windows(qt, ql, ok)):
+            with step_window(self, step, self.arrays, qt, ql, w, cursor, attrs,
+                             fetch=(0, 2)) as (vals, valid):
                 for i in range(len(queries)):
                     if ok[i]:
                         got[i].append(vals[i][valid[i]])
@@ -820,9 +853,11 @@ class BatchedServer:
         # lanes: the (doc, tf) run slots the step gathers; live: the
         # postings of the active queries' terms
         active = np.arange(qt.shape[1])[None, :] < ql[:, None]
-        lanes = (qt.size * int(self.arrays["rank_run_docs"].shape[1]),
-                 int(self._lengths_np[qt][active & ok[:, None]].sum()))
-        with step_window(self, step, self.arrays, qt, ql, 0, (), lanes,
+        counts = dict(
+            lanes=qt.size * int(self.arrays["rank_run_docs"].shape[1]),
+            live_lanes=int(self._lengths_np[qt][active & ok[:, None]].sum()),
+            anchor_steps=0, pool_steps=0)
+        with step_window(self, step, self.arrays, qt, ql, 0, (), counts,
                          fetch=(0, 2)) as (docs, valid):
             empty = np.zeros(0, np.int64)
             return [docs[i][valid[i]].astype(np.int64) if ok[i] else empty
@@ -831,16 +866,17 @@ class BatchedServer:
 
 @contextmanager
 def step_window(server, step, arrays: dict, qt: np.ndarray, ql: np.ndarray,
-                w: int, cursor: tuple, lanes: tuple[int, int],
+                w: int, cursor: tuple, counts: dict,
                 fetch: tuple[int, ...] = (0, 1)):
     """Run one call of ``server``'s ``step`` over sweep window ``w``, whose
-    position the step's ``cursor`` arguments give, under an
-    ``engine.window`` span and yield the host copies of its outputs
-    ``fetch``; the caller's per-query masking runs inside the
-    ``engine.fetch`` span.  ``lanes`` is (padded lanes, live lanes)."""
+    position (and search bounds) the step's ``cursor`` arguments give,
+    under an ``engine.window`` span and yield the host copies of its
+    outputs ``fetch``; the caller's per-query masking runs inside the
+    ``engine.fetch`` span.  ``counts`` become the span's attrs (``lanes``,
+    ``live_lanes``, ``anchor_steps``, ``pool_steps``)."""
     traces = server.trace_events
     with tracing.span("engine.window", program=step.__name__, window=w,
-                      lanes=lanes[0], live_lanes=lanes[1]) as sp:
+                      **counts) as sp:
         outs = step(arrays, jnp.asarray(qt), jnp.asarray(ql), *cursor)
         sp.set(traced=int(server.trace_events > traces))
         outs = [outs[i] for i in fetch]
@@ -871,6 +907,40 @@ def lane_cumsum(arrays: dict) -> np.ndarray:
     cum = np.zeros(rows.shape[:-1] + (rows.shape[-1] + 1,), np.int64)
     np.cumsum(rows, axis=-1, out=cum[..., 1:])
     return cum
+
+
+def longest_entry(c_offsets: np.ndarray, lane_cum: np.ndarray) -> np.ndarray:
+    """Host copy of the postings of each list's longest C entry (0 for an
+    empty list), from :func:`lane_cumsum`.  Made once, at construction."""
+    sizes = np.diff(lane_cum)
+    out = np.zeros(len(c_offsets) - 1, np.int64)
+    full = c_offsets[1:] > c_offsets[:-1]
+    if full.any():
+        # the empty lists between two full ones hold no entry, so each
+        # segment from one full list's start to the next is that list
+        out[full] = np.maximum.reduceat(sizes, c_offsets[:-1][full])
+    return out
+
+
+def search_steps(c_offsets: np.ndarray, longest: np.ndarray | None,
+                 qt: np.ndarray, ql: np.ndarray, live: np.ndarray) -> np.ndarray:
+    """The halvings a window's two binary searches run, int32[2], reckoned
+    once on the host for all lanes: the ``int.bit_length`` of the most C
+    entries of a probed list (anchor search), and of the most postings of
+    one entry of a probed list (pool search; ``longest`` is None on the
+    dense layout, which has none).  The probed lists are term slots 1 ..
+    ``ql`` - 1 of the queries ``live`` in the window, so padding slots and
+    queries without a candidate raise neither count.  A lower-bound search
+    over n entries has converged after ``n.bit_length()`` halvings, so the
+    live lanes answer as under FULL_DEPTH; the others' answers are
+    discarded.  An int32 host array: the step gets it like ``qt``, with no
+    conversion program of its own."""
+    slot = np.arange(qt.shape[1])[None, :]
+    ids = qt[(slot >= 1) & (slot < ql[:, None]) & live[:, None]]
+    widest = int((c_offsets[ids + 1] - c_offsets[ids]).max(initial=0))
+    entry = 0 if longest is None else int(longest[ids].max(initial=0))
+    return np.minimum([widest.bit_length(), entry.bit_length()],
+                      MAX_SEARCH_STEPS).astype(np.int32)
 
 
 def window_live_lanes(c_offsets: np.ndarray, lane_cum: np.ndarray,

@@ -32,7 +32,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..core.anchors import AnchoredIndex, build_anchored
+from ..core.anchors import FULL_DEPTH, AnchoredIndex, build_anchored
 from . import tracing
 from .engine import (
     MAX_CAND_ROWS,
@@ -41,6 +41,7 @@ from .engine import (
     candidates_for,
     encode_queries,
     lane_cumsum,
+    search_steps,
     step_window,
     window_live_lanes,
 )
@@ -128,25 +129,27 @@ class PartitionedAnchoredIndex:
 
 def _local_serve(local: dict, query_terms: jax.Array, query_lens: jax.Array,
                  max_terms: int, mode: str = "and",
-                 row_start: jax.Array | int = 0):
+                 row_start: jax.Array | int = 0, steps=FULL_DEPTH):
     """Shard-local batched queries (same probe loop as engine.make_serve_step,
-    candidates re-based to the shard's id space)."""
+    candidates re-based to the shard's id space; ``steps`` bounds the
+    searches as there)."""
     idx = AnchoredIndex(
         anchors=local["anchors"], c_offsets=local["c_offsets"],
         expand=local["expand"], expand_valid=local["expand_valid"],
         lengths=local["lengths"], expand_len=local["expand"].shape[-1])
     cand_vals, cand_valid = candidates_for(idx, query_terms[:, 0], row_start)
     match = _probe_terms(idx, query_terms, query_lens, cand_vals, cand_valid,
-                         max_terms, phrase=(mode == "phrase"))
+                         max_terms, phrase=(mode == "phrase"), steps=steps)
     # back to global ids
     return cand_vals - 1 + local["doc_base"][0], match
 
 
 def make_partitioned_serve_step(max_terms: int, mesh, shard_axis: str = "data",
                                 mode: str = "and"):
-    """Returns serve(arrays, query_terms, query_lens, row_start=0) ->
-    (vals, mask), each (n_shards, B, C); every probe is shard-local under
-    shard_map.  ``mode`` selects AND or offset-shifted phrase probes."""
+    """Returns serve(arrays, query_terms, query_lens, row_start=0,
+    steps=FULL_DEPTH) -> (vals, mask), each (n_shards, B, C); every probe
+    is shard-local under shard_map, every shard's searches run ``steps``.
+    ``mode`` selects AND or offset-shifted phrase probes."""
 
     in_specs = (
         {k: P(shard_axis, *([None] * (v - 1))) for k, v in
@@ -155,21 +158,23 @@ def make_partitioned_serve_step(max_terms: int, mesh, shard_axis: str = "data",
         P(),  # queries replicated
         P(),
         P(),  # window cursor replicated
+        P(),  # and the searches' bounds
     )
     out_specs = (P(shard_axis, None, None), P(shard_axis, None, None))
 
-    def local_fn(arrays, qt, ql, row_start):
+    def local_fn(arrays, qt, ql, row_start, steps):
         local = {k: v[0] for k, v in arrays.items() if k != "doc_base"}
         local["doc_base"] = arrays["doc_base"]
         vals, mask = _local_serve(local, qt, ql, max_terms, mode=mode,
-                                  row_start=row_start)
+                                  row_start=row_start, steps=steps)
         return vals[None], mask[None]
 
     mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs)
 
-    def serve(arrays, qt, ql, row_start=0):
-        return mapped(arrays, qt, ql, jnp.asarray(row_start, jnp.int32))
+    def serve(arrays, qt, ql, row_start=0, steps=FULL_DEPTH):
+        return mapped(arrays, qt, ql, jnp.asarray(row_start, jnp.int32),
+                      jnp.asarray(steps, jnp.int32))
 
     return serve
 
@@ -281,30 +286,31 @@ class PartitionedServer:
                     max_terms=width, mesh=self.mesh,
                     shard_axis=self.shard_axis, mode=mode)
 
-                def counted(arrays, qt, ql, row_start, _raw=raw):
+                def counted(arrays, qt, ql, row_start, steps, _raw=raw):
                     # runs only while jax traces — counts actual retraces
                     self.trace_events += 1
-                    return _raw(arrays, qt, ql, row_start)
+                    return _raw(arrays, qt, ql, row_start, steps)
 
                 counted.__name__ = counted.__qualname__ = f"serve_{mode}_sharded"
                 serve = jax.jit(counted)
             else:
-                def local(local_arrays, qt, ql, row_start, _mode=mode, _w=width):
+                def local(local_arrays, qt, ql, row_start, steps, _mode=mode,
+                          _w=width):
                     # runs only while jax traces — counts actual retraces
                     self.trace_events += 1
                     return _local_serve(local_arrays, qt, ql, _w, mode=_mode,
-                                        row_start=row_start)
+                                        row_start=row_start, steps=steps)
 
                 local.__name__ = local.__qualname__ = f"serve_{mode}_shard"
                 jitted = jax.jit(local)
 
-                def serve(arrays, qt, ql, row_start, _j=jitted):
+                def serve(arrays, qt, ql, row_start, steps, _j=jitted):
                     outs = []
                     for s in range(self.pidx.n_shards):
                         local_arrays = {k: v[s] for k, v in arrays.items()
                                         if k != "doc_base"}
                         local_arrays["doc_base"] = arrays["doc_base"][s:s + 1]
-                        outs.append(_j(local_arrays, qt, ql, row_start))
+                        outs.append(_j(local_arrays, qt, ql, row_start, steps))
                     vals = jnp.stack([v for v, _ in outs])
                     mask = jnp.stack([m for _, m in outs])
                     return vals, mask
@@ -324,11 +330,18 @@ class PartitionedServer:
         per_row = int(self.pidx.arrays["expand"].shape[-1])
         hits: list[list[np.ndarray]] = [[] for _ in queries]
         for w in range(max(1, -(-rows // MAX_CAND_ROWS))):
-            lanes = (self.pidx.n_shards * qt.shape[0] * MAX_CAND_ROWS * per_row,
-                     window_live_lanes(c, self._lane_cum_np, qt[:, 0][ok],
-                                       w * MAX_CAND_ROWS))
+            start = w * MAX_CAND_ROWS
+            # one bound for all shards: the most any shard's slices need
+            steps = np.max([search_steps(
+                cs, None, qt, ql, ok & (cs[qt[:, 0] + 1] - cs[qt[:, 0]] > start))
+                for cs in c], axis=0)
+            counts = dict(
+                lanes=self.pidx.n_shards * qt.shape[0] * MAX_CAND_ROWS * per_row,
+                live_lanes=window_live_lanes(c, self._lane_cum_np,
+                                             qt[:, 0][ok], start),
+                anchor_steps=int(steps[0]), pool_steps=0)
             with step_window(self, serve, self.pidx.arrays, qt, ql, w,
-                             (w * MAX_CAND_ROWS,), lanes) as (vals, mask):
+                             (start, steps), counts) as (vals, mask):
                 for qi in range(len(queries)):
                     if ok[qi]:
                         hits[qi].append(vals[:, qi][mask[:, qi]])

@@ -29,7 +29,8 @@ The spans of the served path, from the frontend down:
 ``session.plan``       parse, route and grouping into shapes
 ``engine.encode``      padding the term ids of one device batch
 ``engine.window``      one device step call (``program``, ``window``,
-                       ``lanes``, ``live_lanes``, ``traced``)
+                       ``lanes``, ``live_lanes``, ``anchor_steps``,
+                       ``pool_steps``, ``traced``)
 ``engine.device_wait`` the wait for that step's outputs
 ``engine.fetch``       their copy to the host and the per-query masking
                        (``bytes``)

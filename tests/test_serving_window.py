@@ -71,9 +71,9 @@ def test_and_at_window_boundaries(boundary_index, length):
     # the dense sweep runs exactly ceil(L / MAX_CAND_ROWS) windows; the
     # fused one as many as the lane budget packs (one here: L < Cq)
     qt, ql, ok = servers["dense"].encode([q], sort_by_length=True)
-    assert servers["dense"]._n_windows(qt, ok) == -(-length // MAX_CAND_ROWS)
+    assert servers["dense"]._n_windows(qt, ql, ok) == -(-length // MAX_CAND_ROWS)
     fused = servers["fused"]
-    assert fused._n_windows(qt, ok) == len(lane_budget_windows(fused, wid)) == 1
+    assert fused._n_windows(qt, ql, ok) == len(lane_budget_windows(fused, wid)) == 1
 
 
 @pytest.mark.parametrize("length", BOUNDARY_LENGTHS)
@@ -177,9 +177,9 @@ def test_fused_sweep_at_lane_budget_boundaries(lane_index, length):
         assert len(want) > 0, q
     for server in (session.server, session.positional_server):
         assert server.layout == "fused"
-        qt, _, ok = server.encode([[f"w{length}"]])
+        qt, ql, ok = server.encode([[f"w{length}"]])
         windows = lane_budget_windows(server, int(qt[0, 0]))
-        assert server._n_windows(qt, ok) == len(windows)
+        assert server._n_windows(qt, ql, ok) == len(windows)
         if grammar == "postings":  # one posting per entry: ceil(L / Cq)
             assert len(windows) == -(-length // CQ)
 
@@ -214,8 +214,8 @@ def test_fused_batch_whose_queries_finish_in_different_windows():
     got = server.doclist(queries)
     for q, dev in zip(queries, got):
         assert np.array_equal(dev, host.execute("docs: " + " ".join(q))), q
-    qt, _, ok = server.encode(queries, sort_by_length=True)
+    qt, ql, ok = server.encode(queries, sort_by_length=True)
     per_query = [len(lane_budget_windows(server, int(qt[i, 0])))
                  for i in range(len(queries)) if ok[i]]
-    assert server._n_windows(qt, ok) == max(per_query)
+    assert server._n_windows(qt, ql, ok) == max(per_query)
     assert len(set(per_query)) > 1, per_query  # they finish apart

@@ -168,7 +168,8 @@ def test_fused_serve_step_fits_v5e(one_chip, mode, doclist, topk):
 def test_named_and_step_compiles_for_v5e_at_the_cell_shape(one_chip):
     """The server's own AND step, under its program name and scopes, at
     the benchmark cell's window: a batch of 16 over a max_phrase of
-    16,384, so 16 x 16,384 lanes."""
+    16,384, so 16 x 16,384 lanes, with the searches' step counts passed
+    as the server passes them."""
     import numpy as np
 
     from repro.serving.engine import AND, BatchedServer
@@ -179,8 +180,9 @@ def test_named_and_step_compiles_for_v5e_at_the_cell_shape(one_chip):
                            _lane_cum_np=np.zeros(1))
     index = _fused_index(one_chip, MAIN_DOCS, NP_ANCHORS, NP_POOL,
                          NP_MAX_PHRASE, positional=False)
+    steps = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
     compiled = server._step(AND, 2).lower(
-        index, *_queries(one_chip, 16, fused=True)).compile()
+        index, *_queries(one_chip, 16, fused=True), steps).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_serve_and")
     assert "anchor_search" in text and "pool_search" in text
@@ -247,7 +249,7 @@ def test_partitioned_step_compiles_for_v5e_mesh(mesh, mode):
               "lengths": s((4, N_LISTS)), "doc_base": s((4,))}
     per_kind = four["per_kind"]
     queries = (s((per_kind, 2), spec=P()), s((per_kind,), spec=P()),
-               s((), spec=P()))
+               s((), spec=P()), s((2,), spec=P()))  # cursor, search bounds
     step = make_partitioned_serve_step(max_terms=2, mesh=mesh, mode=mode)
     compiled = _compile(step, arrays, *queries)
     text = compiled.as_text()

@@ -111,8 +111,8 @@ def test_a_micro_batch_leaves_one_span_tree(session):
     # budget, max_phrase rounded up, holds each of these lists whole
     server = session.server
     assert server.layout == "fused"
-    qt, _, ok = server.encode([q.split() for q in QUERIES], sort_by_length=True)
-    assert len(windows) == server._n_windows(qt, ok) == 1
+    qt, ql, ok = server.encode([q.split() for q in QUERIES], sort_by_length=True)
+    assert len(windows) == server._n_windows(qt, ql, ok) == 1
 
     # the frontend's latency samples are the request spans
     lat = fe.metrics()["latency"]
@@ -141,9 +141,9 @@ def test_live_lanes_match_a_brute_count(index, postings_index, layout):
     server.conjunctive(queries)
     windows = [r for r in tracing.spans(t0, time.perf_counter()).records
                if r.name == "engine.window"]
-    qt, _, ok = server.encode(queries, sort_by_length=True)
+    qt, ql, ok = server.encode(queries, sort_by_length=True)
     assert not ok[-1]
-    assert len(windows) == server._n_windows(qt, ok)
+    assert len(windows) == server._n_windows(qt, ql, ok)
     c_off = np.asarray(server.arrays["c_offsets"])
     row_len = (np.asarray(server.arrays["c_len"]) if layout == "fused"
                else np.asarray(server.arrays["expand_valid"]).sum(axis=1))
@@ -243,7 +243,7 @@ def test_device_programs_are_named_after_what_they_serve(
     server = servers[PHRASE if kind == PHRASE else AND]
     qt = np.zeros((4, 2), np.int32)
     ql = np.full(4, 2, np.int32)
-    cursor, _ = next(server._windows(qt, np.ones(4, bool)))
+    cursor, _ = next(server._windows(qt, ql, np.ones(4, bool)))
     traces = server.trace_count
     lowered = server._step(kind, 2, topk=topk, doclist=doclist).lower(
         server.arrays, qt, ql, *cursor)
@@ -256,7 +256,7 @@ def test_the_and_step_carries_its_scopes(session):
     assert server.layout == "fused"
     qt = np.zeros((4, 2), np.int32)
     ql = np.full(4, 2, np.int32)
-    cursor, _ = next(server._windows(qt, np.ones(4, bool)))
+    cursor, _ = next(server._windows(qt, ql, np.ones(4, bool)))
     hlo = server._step(AND, 2).lower(server.arrays, qt, ql,
                                      *cursor).compile().as_text()
     assert hlo.startswith("HloModule jit_serve_and")
