@@ -4,23 +4,13 @@
 * anchor_intersect — batched anchor probes: RePair-Skip on the VPU
 * fused_decode     — per-row bounded rule expansion (+ fused membership
                      probe) for the compressed device layout
-* embedding_bag    — scalar-prefetch gather + bag-sum: recsys lookup
-* cin_interaction  — fused xDeepFM CIN layer on the MXU
-* flash_attention  — causal GQA flash forward (TPU fast path of models.flash)
-* moe_gemm         — grouped expert GEMM over the MoE dispatch buffer
-* flash_decode     — split-KV single-token decode attention (serve path)
 * minhash_sig      — batched MinHash signatures: min-reduction over hashed
                      shingles (version-structure mining)
 """
 
 from .anchor_intersect.ops import anchor_probe
-from .cin_interaction.ops import cin_layer
 from .dgap_decode.ops import dgap_decode
-from .embedding_bag.ops import embedding_bag
-from .flash_attention.ops import flash_attention_tpu
-from .flash_decode.ops import flash_decode
 from .fused_decode.ops import decode_rows, probe_rows
 from .minhash_sig.ops import hash_params, minhash_signatures
-from .moe_gemm.ops import moe_gemm
 
-__all__ = ["anchor_probe", "cin_layer", "decode_rows", "dgap_decode", "embedding_bag", "flash_attention_tpu", "hash_params", "minhash_signatures", "moe_gemm", "flash_decode", "probe_rows"]
+__all__ = ["anchor_probe", "decode_rows", "dgap_decode", "hash_params", "minhash_signatures", "probe_rows"]

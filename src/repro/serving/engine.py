@@ -1,15 +1,14 @@
-"""Batched device serving + legacy engine shims.
+"""Batched device serving: the device-side steps and their host driver.
 
-The serving stack is now plan-first (PR 4):
+The serving stack is plan-first:
 
 * ``serving.plan`` — `parse_query` → logical plan → cost-aware compiler →
   physical plan (`route_query` / `compile_query` / EXPLAIN rendering).
 * ``serving.session.Session`` — the **only** entry point: plan-cached,
   jit-bucket-grouped `execute`, plus `explain` and `metrics`.
-* this module — the device-side batched steps (:func:`make_serve_step`),
-  the windowed-exact device driver (:class:`BatchedServer`), and thin
-  **deprecation shims** (:class:`QueryEngine`, :class:`QueryPlanner`) that
-  keep the old per-kind call sites working for one PR.
+* this module — the device-side batched steps (:func:`make_serve_step`)
+  and the windowed-exact device driver (:class:`BatchedServer`).  It
+  imports nothing from ``serving.session``.
 
 Device-step geometry: padded (batch, width) term-id matrices; each step
 generates candidates from the query's first list via the bounded expansion
@@ -28,7 +27,6 @@ doc ids and dedups on device with a segment-max scan.
 
 from __future__ import annotations
 
-import warnings
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
@@ -51,135 +49,7 @@ from ..core.doclist import BM25_B, BM25_K1, bm25_idf
 from ..core.index import NonPositionalIndex, PositionalIndex
 from ..core.registry import CAP_DEVICE_RESIDENT, capabilities_of
 from . import tracing
-from .plan import (  # noqa: F401  (re-exported: the legacy import surface)
-    AND,
-    DOCS,
-    DOCS_TOPK,
-    MAX_CAND_ROWS,
-    PHRASE,
-    RANK,
-    SERVER_KINDS,
-    TOPK,
-    WORD,
-    ParsedQuery,
-    parse_query,
-    route_query,
-    width_bucket,
-)
-from .session import Session
-
-
-@dataclass(frozen=True)
-class QueryPlan:
-    """Legacy plan record (the pre-IR surface): see ``serving.plan.Route``
-    and ``Session.explain`` for the first-class replacement."""
-
-    query: ParsedQuery
-    index: str  # "nonpositional" | "positional"
-    route: str  # "host" | "device"
-    strategy: str  # host physical operator or device step name
-
-
-class QueryPlanner:
-    """Deprecated routing shim: ``plan`` wraps the plan compiler's
-    :func:`repro.serving.plan.route_query` decision into the legacy
-    :class:`QueryPlan` record.  Use ``Session.explain`` / ``Session.plan``."""
-
-    def __init__(self, engine: "QueryEngine"):
-        self.engine = engine
-
-    def plan(self, q, prefer_device: bool = True) -> QueryPlan:
-        pq = parse_query(q)
-        rt = route_query(self.engine, pq, prefer_device=prefer_device)
-        return QueryPlan(pq, rt.index, rt.route, rt.strategy)
-
-
-# ----------------------------------------------------------------------
-# legacy host engine (deprecation shim over Session)
-# ----------------------------------------------------------------------
-_DEPRECATION_WARNED = False
-
-
-def _warn_deprecated(method: str) -> None:
-    global _DEPRECATION_WARNED
-    if not _DEPRECATION_WARNED:
-        _DEPRECATION_WARNED = True
-        warnings.warn(
-            f"QueryEngine.{method} (and the other per-kind QueryEngine "
-            f"methods) are deprecated: build a repro.serving.session.Session "
-            f"and go through Session.execute / Session.explain",
-            DeprecationWarning, stacklevel=3)
-
-
-@dataclass
-class QueryEngine:
-    """Deprecated facade: every call delegates to an owned
-    :class:`~repro.serving.session.Session` (``.session``).  ``execute`` /
-    ``batch`` stay silent for migration; the per-kind methods emit one
-    ``DeprecationWarning`` per process."""
-
-    # a positional-only engine (index=None) still serves phrase and document
-    # listing queries through the doc-run / grammar structures
-    index: NonPositionalIndex | None
-    positional: PositionalIndex | None = None
-    server: "BatchedServer | None" = None  # device path over `index`
-    positional_server: "BatchedServer | None" = None  # device path over `positional`
-
-    def __post_init__(self):
-        self.session = Session(index=self.index, positional=self.positional,
-                               server=self.server,
-                               positional_server=self.positional_server)
-        self.planner = QueryPlanner(self)
-
-    def __setattr__(self, name, value):
-        # keep the owned Session live: old call sites attach servers (or swap
-        # indexes) after construction, and routes planned under the previous
-        # configuration must not be served from the cache
-        object.__setattr__(self, name, value)
-        if (name in ("index", "positional", "server", "positional_server")
-                and getattr(self, "session", None) is not None):
-            setattr(self.session, name, value)
-            self.session._plan_cache.clear()
-
-    def execute(self, q) -> np.ndarray:
-        """Plan and run one query (a list of words is the legacy AND form)."""
-        return self.session.execute(parse_query(q))
-
-    def batch(self, queries: list) -> list[np.ndarray]:
-        """Serve a mixed batch in original order (see ``Session.execute``)."""
-        return self.session.execute(list(queries))
-
-    def doc_runs(self):
-        return self.session.doc_runs()
-
-    # -- deprecated per-kind surface ------------------------------------
-    def word(self, w: str) -> np.ndarray:
-        _warn_deprecated("word")
-        return self.session._word(w)
-
-    def conjunctive(self, words: list[str]) -> np.ndarray:
-        _warn_deprecated("conjunctive")
-        return self.session._conjunctive(words)
-
-    and_ = conjunctive
-
-    def phrase(self, tokens: list[str]) -> np.ndarray:
-        _warn_deprecated("phrase")
-        return self.session._phrase(tokens)
-
-    def ranked_and(self, words: list[str], k: int = 10) -> np.ndarray:
-        _warn_deprecated("ranked_and")
-        return self.session._ranked_and(words, k=k)
-
-    topk = ranked_and
-
-    def doc_list(self, terms: list[str], phrase: bool = False) -> np.ndarray:
-        _warn_deprecated("doc_list")
-        return self.session._doc_list(terms, phrase=phrase)
-
-    def doc_topk(self, terms: list[str], k: int = 10, phrase: bool = False) -> np.ndarray:
-        _warn_deprecated("doc_topk")
-        return self.session._doc_topk(terms, k=k, phrase=phrase)
+from .plan import AND, MAX_CAND_ROWS, PHRASE, RANK, SERVER_KINDS
 
 
 def _lookup(index, term: str):
@@ -531,12 +401,6 @@ def make_ranked_step(max_terms: int = 8, topk: int = 10):
         return top_docs, top_scores, top_scores > 0.0
 
     return serve
-
-
-def make_uihrdc_serve_step(max_terms: int = 8):
-    """The AND-only step of the ``uihrdc`` dry-run arch (kept as the
-    compiled entry point; see :func:`make_serve_step` for phrase/top-k)."""
-    return make_serve_step(max_terms=max_terms, mode=AND)
 
 
 # ----------------------------------------------------------------------

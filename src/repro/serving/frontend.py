@@ -258,9 +258,9 @@ class _Replica:
 class ReplicatedServer:
     """N replicas of one index's batched server behind least-loaded
     dispatch — implements the batched-server protocol (``conjunctive`` /
-    ``phrase`` / ``topk`` / ``doclist`` / ``encode`` / ``kinds`` /
-    ``trace_count``) so a :class:`Session` routes device traffic onto it
-    exactly like onto a single server.
+    ``phrase`` / ``topk`` / ``doclist`` / ``ranked`` / ``encode`` /
+    ``kinds`` / ``trace_count``) so a :class:`Session` routes device
+    traffic onto it exactly like onto a single server.
 
     Dispatch picks the healthy replica with the fewest in-flight batches
     (ties: fewest queries served).  A replica raising mid-batch is marked
@@ -353,6 +353,9 @@ class ReplicatedServer:
 
     def doclist(self, queries, phrase: bool = False, width=None):
         return self._dispatch("doclist", queries, phrase=phrase, width=width)
+
+    def ranked(self, queries, k: int = 10, width=None):
+        return self._dispatch("ranked", queries, k=k, width=width)
 
     def encode(self, queries, sort_by_length: bool = False, width=None):
         return self._pick().server.encode(queries, sort_by_length=sort_by_length,

@@ -42,8 +42,8 @@ Cost model (deterministic integer proxies; ``lg x = bitlength(x)``):
   cost ~rows, generic reduce costs child rows.
 * ``TopK``: rows = min(k, child rows), cost = child rows · lg k.
 
-:func:`route_query` is the pure routing decision (shared by
-``Session`` and the legacy ``QueryPlanner``); it is a function of the
+:func:`route_query` is the pure routing decision that ``Session.plan``
+caches; it is a function of the
 query *shape*, not the concrete terms, except for the all-terms-known
 check — :func:`plan_key` captures exactly that shape, so compiled routes
 are cacheable per (structure, backend, batch bucket).
@@ -72,7 +72,7 @@ from ..core.registry import (
 )
 
 # candidate C-entries taken from the driving list per device window (the
-# geometry of the windowed sweep; re-exported by serving.engine)
+# geometry of the windowed sweep)
 MAX_CAND_ROWS = 64
 
 # query kinds
@@ -318,7 +318,7 @@ def logical_plan(q, extract: int | None = None) -> Logical:
 
 
 # ----------------------------------------------------------------------
-# routing: the shape-level decision shared by Session and QueryPlanner
+# routing: the shape-level decision Session caches per plan shape
 # ----------------------------------------------------------------------
 #: device-step kinds a full BatchedServer can serve; partial servers (the
 #: partitioned driver) declare their own ``kinds`` subset
@@ -328,7 +328,7 @@ SERVER_KINDS = frozenset({AND, PHRASE, TOPK, DOCS})
 @dataclass(frozen=True)
 class Route:
     """Where one query shape executes: which index, host or device, the
-    strategy label (legacy ``QueryPlan.strategy`` vocabulary), and — for
+    strategy label (device step or host operator name), and — for
     device routes — the padded term-matrix width bucket."""
 
     index: str  # "nonpositional" | "positional"

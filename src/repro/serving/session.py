@@ -2,8 +2,7 @@
 
 A :class:`Session` owns the built indexes (non-positional and/or
 positional), the optional batched device servers, a **plan cache**, and the
-host execution operators.  Everything the old per-kind ``QueryEngine``
-surface did now flows through two methods:
+host execution operators.  Every query kind flows through two methods:
 
 * :meth:`Session.execute` — serve one query or a heterogeneous batch.
   Every query is parsed, routed through the plan compiler
@@ -19,9 +18,7 @@ surface did now flows through two methods:
 * :meth:`Session.explain` — the costed physical operator tree for a query
   as text or JSON, without executing it.
 
-The legacy ``QueryEngine`` / ``BatchedServer.{conjunctive,phrase,...}``
-surfaces remain as thin shims over a ``Session`` for one PR (they emit a
-``DeprecationWarning``); new code should build a Session directly:
+Build a Session directly:
 
     sess = Session.build(index, positional=pidx)      # device-attached
     results = sess.execute(["w1 w2", '"a b"', "top5: w1 w2"])
@@ -63,6 +60,7 @@ from ..core.doclist import (
 from ..core.index import NonPositionalIndex, PositionalIndex
 from ..core.writer import IndexWriter, is_writer_dir
 from . import tracing
+from .engine import BatchedServer
 from .plan import (
     AND,
     DOCS,
@@ -142,7 +140,6 @@ class Session:
         picks the device posting memory model ("dense" | "fused"; "auto"
         fuses device-resident Re-Pair stores, densifies the rest)."""
         from ..core.registry import FAMILY_SELFINDEX, get_backend_spec
-        from .engine import BatchedServer
 
         def attach(ix):
             return (device and ix is not None

@@ -1,5 +1,6 @@
 """Shared fixtures. NOTE: no XLA device-count flags here — smoke tests and
-benches must see 1 device (the dry-run sets its own 512-device env)."""
+benches must see 1 device (test_distributed sets its own 8-device env in a
+subprocess)."""
 
 import numpy as np
 import pytest

@@ -1,13 +1,7 @@
-"""Data pipelines: collections, LM batches, neighbor sampler, recsys logs."""
+"""Collections and tokenization."""
 
-import numpy as np
-import pytest
-
-from repro.configs import get_config
 from repro.data import generate_collection
-from repro.data.graphs import NeighborSampler, graph_batches, molecule_batches, synthetic_graph
-from repro.data.pipelines import lm_batches, recsys_batches
-from repro.data.text import Vocabulary, detokenize, tokenize
+from repro.data.text import detokenize, tokenize
 
 
 def test_tokenize_roundtrip():
@@ -27,56 +21,3 @@ def test_collection_structures_differ():
     cha = generate_collection(structure="chaotic", seed=1, n_articles=2,
                               versions_per_article=4, words_per_doc=30)
     assert lin.docs != cha.docs
-
-
-def test_lm_batches_shapes():
-    cfg = get_config("granite-3-2b").reduced()
-    it = lm_batches(cfg, 4, 32, seed=0)
-    b = next(it)
-    assert b["tokens"].shape == (4, 32) and b["targets"].shape == (4, 32)
-    assert b["tokens"].max() < cfg.vocab_size
-    # targets are next tokens
-    b2 = next(it)
-    assert not np.array_equal(b["tokens"], b2["tokens"])
-
-
-def test_neighbor_sampler_block_structure():
-    g = synthetic_graph(300, 5, 8, 3, seed=2)
-    s = NeighborSampler(g, seed=0)
-    block = s.sample_block(np.arange(10), (4, 2))
-    n0, n1, n2 = 10, 40, 80
-    assert block["node_feat"].shape == (n0 + n1 + n2, 8)
-    assert block["edge_src"].shape == (n1 + n2,)
-    # edges point from deeper layers into shallower ones
-    assert block["edge_src"][:n1].min() >= n0
-    assert block["edge_dst"][:n1].max() < n0
-
-
-def test_sampled_neighbors_are_real_edges():
-    g = synthetic_graph(200, 6, 4, 3, seed=3)
-    s = NeighborSampler(g, seed=1)
-    seeds = np.asarray([0, 5, 9])
-    block = s.sample_block(seeds, (3,))
-    edge_set = set(zip(g.edge_src.tolist(), g.edge_dst.tolist()))
-    all_nodes = np.concatenate([seeds, np.zeros(0)])
-    feat = block["node_feat"]
-    # layer-1 nodes' features match real graph nodes that are in-neighbors
-    for j in range(3, feat.shape[0]):
-        # feature row must exist in the graph's feature matrix
-        diffs = np.abs(g.node_feat - feat[j]).sum(1)
-        assert diffs.min() < 1e-6
-
-
-def test_molecule_batches():
-    it = molecule_batches(8, 10, 20, 4, 2, seed=0)
-    b = next(it)
-    assert b["node_feat"].shape == (8, 10, 4)
-    assert b["edge_src"].shape == (8, 20)
-
-
-@pytest.mark.parametrize("arch", ["fm", "xdeepfm", "sasrec", "two-tower-retrieval"])
-def test_recsys_batches(arch):
-    cfg = get_config(arch).reduced()
-    b = next(recsys_batches(cfg, 8, seed=0))
-    for v in b.values():
-        assert len(v) == 8

@@ -7,7 +7,7 @@ rates — including the degenerate 0% (all versions identical: maximal
 repetitiveness) and 100% (every word position mutated) — and asserts every
 registered backend returns byte-identical word / AND / phrase / topk /
 docs / docs-topk answers vs a brute-force NumPy reference, through the same
-index / engine API.
+``Session`` API.
 
 Reproduction: every assertion message carries the ``(seed, edit_rate,
 store, query)`` tuple that produced it; the base seed can be pinned with
@@ -24,10 +24,11 @@ from repro.core.artifact import open_index, save_index
 from repro.core.index import NonPositionalIndex, PositionalIndex
 from repro.core.registry import backend_names
 from repro.core.writer import IndexWriter
-from repro.data import generate_collection
-from repro.data.text import STOPWORDS, is_word_token, tokenize
-from repro.serving.engine import BatchedServer, QueryEngine, parse_query
+from repro.serving.engine import BatchedServer
+from repro.serving.plan import parse_query
 from repro.serving.session import Session
+
+from refcase import RefCase, as_reference
 
 BASE_SEED = int(os.environ.get("REPRO_DIFF_SEED", "20260727"))
 EDIT_RATES = (0.0, 0.2, 1.0)  # none / moderate / total mutation
@@ -37,120 +38,6 @@ ALL_BACKENDS = backend_names()
 # run-length (rice_runs), LZ (vbyte_lzend), grammar (repair_skip),
 # self-index (rlcsa), referential (rlz — mined-cluster heads)
 FAMILY_REPS = ("rice_runs", "vbyte_lzend", "repair_skip", "rlcsa", "rlz")
-
-
-# ----------------------------------------------------------------------
-# randomized fixtures + NumPy reference
-# ----------------------------------------------------------------------
-class RefCase:
-    """One randomized collection plus its brute-force answers."""
-
-    def __init__(self, rate: float, seed: int):
-        self.rate = rate
-        self.seed = seed
-        self.col = generate_collection(n_articles=2, versions_per_article=4,
-                                       words_per_doc=45, edit_rate=rate,
-                                       seed=seed)
-        self.docs = self.col.docs
-        # folded word-token sets / counts per doc (non-positional semantics)
-        self.word_sets = []
-        self.tok_lists = []
-        self.term_lists = []  # default-analyzer terms per doc (BM25 semantics)
-        for doc in self.docs:
-            toks = tokenize(doc)
-            self.tok_lists.append(toks)
-            terms = [t.lower() for t in toks if is_word_token(t)
-                     and t.lower() not in STOPWORDS]
-            self.term_lists.append(terms)
-            self.word_sets.append(set(terms))
-        # reference vocab (identical across backends): build once with vbyte
-        self.ref_np = NonPositionalIndex.build(self.docs, store="vbyte")
-        self.ref_pos = PositionalIndex.build(self.docs, store="vbyte",
-                                             keep_text=True)
-        self.stream = self.ref_pos.token_stream
-
-    # -- brute-force answers -------------------------------------------
-    def brute_docs(self, words) -> np.ndarray:
-        if any(self.ref_np.word_id(w) is None for w in words):
-            return np.zeros(0, dtype=np.int64)
-        return np.asarray([d for d, s in enumerate(self.word_sets)
-                           if all(w in s for w in words)], dtype=np.int64)
-
-    def brute_phrase(self, toks) -> np.ndarray:
-        ids = [self.ref_pos.token_id(t) for t in toks]
-        if any(i is None for i in ids):
-            return np.zeros(0, dtype=np.int64)
-        m = len(ids)
-        s = self.stream
-        return np.asarray([p for p in range(len(s) - m + 1)
-                           if all(s[p + j] == ids[j] for j in range(m))],
-                          dtype=np.int64)
-
-    def brute_phrase_docs(self, toks) -> np.ndarray:
-        pos = self.brute_phrase(toks)
-        d = np.searchsorted(self.ref_pos.doc_starts, pos, side="right") - 1
-        return np.unique(d)
-
-    def brute_bm25(self, words, k: int) -> np.ndarray:
-        """Independent BM25 top-k over the OR of ``words`` (float64,
-        Lucene-style non-negative idf, ties by lowest doc id) — the
-        reference every backend's ``rank<k>:`` answer must match."""
-        k1, b = 1.2, 0.75
-        n = len(self.term_lists)
-        avgdl = sum(len(t) for t in self.term_lists) / max(1, n)
-        scores = np.zeros(n)
-        for w in dict.fromkeys(words):  # dedup: one contribution per term
-            df = sum(1 for s in self.word_sets if w in s)
-            if df == 0:
-                continue  # unknown terms score nothing, query still answers
-            idf = np.log1p((n - df + 0.5) / (df + 0.5))
-            for d, terms in enumerate(self.term_lists):
-                tf = terms.count(w)
-                if tf:
-                    dl = len(terms)
-                    scores[d] += idf * tf * (k1 + 1) / (
-                        tf + k1 * (1 - b + b * dl / avgdl))
-        hit = np.nonzero(scores > 0)[0]
-        order = sorted(hit.tolist(), key=lambda d: (-scores[d], d))
-        return np.asarray(order[:k], dtype=np.int64)
-
-    def brute_docs_topk(self, words, k: int) -> np.ndarray:
-        docs = self.brute_docs(words)
-        if len(docs) == 0:
-            return docs
-        scores = np.asarray([sum(self.tok_lists[d].count(w) for w in words)
-                             for d in docs], dtype=np.int64)
-        order = np.argsort(-scores, kind="stable")
-        return docs[order][:k]
-
-    def sample_queries(self, rng) -> list[tuple[str, np.ndarray]]:
-        """(query string, brute reference) pairs drawn from the collection."""
-        vocab = self.ref_np.vocab.id_to_token
-        w = [vocab[int(rng.integers(len(vocab)))] for _ in range(6)]
-        toks = self.tok_lists[int(rng.integers(len(self.docs)))]
-        i = int(rng.integers(0, max(1, len(toks) - 3)))
-        ph = toks[i : i + 2]
-        ph3 = toks[i : i + 3]
-        out = [
-            (w[0], self.brute_docs([w[0]])),
-            (f"{w[1]} {w[2]}", self.brute_docs([w[1], w[2]])),
-            (f"{w[0]} {w[3]} {w[4]}", self.brute_docs([w[0], w[3], w[4]])),
-            ('"' + " ".join(ph) + '"', self.brute_phrase(ph)),
-            ('"' + " ".join(ph3) + '"', self.brute_phrase(ph3)),
-            (f"top4: {w[1]} {w[2]}", self.brute_docs([w[1], w[2]])[:4]),
-            (f"docs: {w[0]}", self.brute_docs([w[0]])),
-            (f"docs: {w[1]} {w[2]}", self.brute_docs([w[1], w[2]])),
-            ('docs: "' + " ".join(ph) + '"', self.brute_phrase_docs(ph)),
-            (f"docs-top3: {w[1]} {w[2]}", self.brute_docs_topk([w[1], w[2]], 3)),
-            ("docs: zzz-never-a-word", np.zeros(0, dtype=np.int64)),
-            (f"rank4: {w[1]} {w[2]}", self.brute_bm25([w[1], w[2]], 4)),
-            (f"rank3: {w[0]} {w[3]} {w[4]}",
-             self.brute_bm25([w[0], w[3], w[4]], 3)),
-            (f"rank5: {w[5]}", self.brute_bm25([w[5]], 5)),
-            (f"rank4: {w[2]} zzz-never-a-word",
-             self.brute_bm25([w[2], "zzz-never-a-word"], 4)),
-        ]
-        return out
 
 
 @pytest.fixture(scope="module", params=EDIT_RATES, ids=lambda r: f"rate={r}")
@@ -173,12 +60,10 @@ def rt_case() -> RefCase:
 def test_backend_matches_reference(case, store):
     idx = NonPositionalIndex.build(case.docs, store=store)
     pidx = PositionalIndex.build(case.docs, store=store)
-    engine = QueryEngine(idx, positional=pidx)
+    session = Session(idx, positional=pidx)
     rng = np.random.default_rng(case.seed + 1)
     for q, ref in case.sample_queries(rng):
-        got = np.asarray(engine.execute(q))
-        if parse_query(q).kind in ("word", "and", "phrase"):
-            got = np.sort(np.unique(got))
+        got = as_reference(q, session.execute(q))
         assert got.dtype == ref.dtype and np.array_equal(got, ref), (
             f"differential mismatch: seed={case.seed} edit_rate={case.rate} "
             f"store={store!r} query={q!r} got={got.tolist()} "
@@ -191,9 +76,9 @@ def test_backend_matches_reference(case, store):
 def test_doc_listing_identical_across_families(case):
     """Acceptance: docs / docs-topk answers agree byte-for-byte across the
     run-length, LZ, grammar, and self-index families."""
-    engines = {}
+    sessions = {}
     for store in FAMILY_REPS:
-        engines[store] = QueryEngine(
+        sessions[store] = Session(
             NonPositionalIndex.build(case.docs, store=store),
             positional=PositionalIndex.build(case.docs, store=store))
     rng = np.random.default_rng(case.seed + 2)
@@ -201,9 +86,9 @@ def test_doc_listing_identical_across_families(case):
                if parse_query(q).kind in ("docs", "docs_topk")]
     base = FAMILY_REPS[0]
     for q in queries:
-        want = np.asarray(engines[base].execute(q))
+        want = np.asarray(sessions[base].execute(q))
         for store in FAMILY_REPS[1:]:
-            got = np.asarray(engines[store].execute(q))
+            got = np.asarray(sessions[store].execute(q))
             assert got.dtype == want.dtype and np.array_equal(got, want), (
                 f"family drift: seed={case.seed} edit_rate={case.rate} "
                 f"query={q!r} {base}={want.tolist()} {store}={got.tolist()}")
@@ -226,9 +111,7 @@ def test_artifact_roundtrip_matches_reference(rt_case, store, tmp_path):
     session = Session(idx, positional=pidx)
     rng = np.random.default_rng(case.seed + 4)
     for q, ref in case.sample_queries(rng):
-        got = np.asarray(session.execute(q))
-        if parse_query(q).kind in ("word", "and", "phrase"):
-            got = np.sort(np.unique(got))
+        got = as_reference(q, session.execute(q))
         assert got.dtype == ref.dtype and np.array_equal(got, ref), (
             f"artifact round-trip mismatch: seed={case.seed} "
             f"edit_rate={case.rate} store={store!r} query={q!r} "
@@ -321,16 +204,16 @@ def test_device_doclist_matches_host(case):
     windowed sweep) returns exactly the host answers."""
     idx = NonPositionalIndex.build(case.docs, store="repair_skip")
     pidx = PositionalIndex.build(case.docs, store="repair_skip")
-    dev = QueryEngine(idx, positional=pidx,
-                      server=BatchedServer.from_index(idx),
-                      positional_server=BatchedServer.from_index(pidx))
-    host = QueryEngine(idx, positional=pidx)
+    dev = Session(idx, positional=pidx,
+                  server=BatchedServer.from_index(idx),
+                  positional_server=BatchedServer.from_index(pidx))
+    host = Session(idx, positional=pidx)
     rng = np.random.default_rng(case.seed + 3)
     queries = [q for q, _ in case.sample_queries(rng)
                if parse_query(q).kind == "docs"]
-    plans = [dev.planner.plan(q) for q in queries]
+    plans = [dev.plan(q) for q in queries]
     assert any(p.route == "device" for p in plans), queries
-    got = dev.batch(queries)
+    got = dev.execute(queries)
     for q, g in zip(queries, got):
         h = np.asarray(host.execute(q))
         assert np.array_equal(np.asarray(g), h), (

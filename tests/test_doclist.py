@@ -19,7 +19,9 @@ from repro.core.doclist import (
 from repro.core.index import NonPositionalIndex, PositionalIndex
 from repro.data import generate_collection
 from repro.data.text import tokenize
-from repro.serving.engine import BatchedServer, QueryEngine, parse_query
+from repro.serving.engine import BatchedServer
+from repro.serving.plan import parse_query
+from repro.serving.session import Session
 
 
 @pytest.fixture(scope="module")
@@ -127,52 +129,53 @@ def test_parse_docs_query_forms():
 def test_planner_doclist_strategies(col, pidx):
     idx = NonPositionalIndex.build(col.docs, store="repair_skip")
     toks = tokenize(col.docs[0])[:2]
-    eng = QueryEngine(idx, positional=pidx)
-    assert eng.planner.plan(f"docs: {toks[0]} {toks[1]}").strategy.startswith("doclist+")
-    assert eng.planner.plan(f'docs: "{toks[0]}"').strategy == "grammar-doclist"
-    assert eng.planner.plan(f'docs: "{toks[0]} {toks[1]}"').strategy == "reduce-doclist"
-    si = QueryEngine(NonPositionalIndex.build(col.docs[:6], store="rlcsa"),
-                     positional=PositionalIndex.build(col.docs[:6], store="rlcsa"))
-    assert si.planner.plan(f'docs: "{toks[0]} {toks[1]}"').strategy == "self-doclist"
-    # positional-only engine: docs queries route to the positional index
-    ponly = QueryEngine(None, positional=pidx)
-    pl = ponly.planner.plan(f"docs: {toks[0]}")
+    sess = Session(idx, positional=pidx)
+    assert sess.plan(f"docs: {toks[0]} {toks[1]}").strategy.startswith("doclist+")
+    assert sess.plan(f'docs: "{toks[0]}"').strategy == "grammar-doclist"
+    assert sess.plan(f'docs: "{toks[0]} {toks[1]}"').strategy == "reduce-doclist"
+    si = Session(NonPositionalIndex.build(col.docs[:6], store="rlcsa"),
+                 positional=PositionalIndex.build(col.docs[:6], store="rlcsa"))
+    assert si.plan(f'docs: "{toks[0]} {toks[1]}"').strategy == "self-doclist"
+    # positional-only session: docs queries route to the positional index
+    ponly = Session(None, positional=pidx)
+    pl = ponly.plan(f"docs: {toks[0]}")
     assert pl.index == "positional" and pl.strategy == "grammar-doclist"
-    vb = QueryEngine(None, positional=PositionalIndex.build(col.docs[:6], store="vbyte"))
-    assert vb.planner.plan(f"docs: {toks[0]}").strategy == "doc-runs"
+    vb = Session(None, positional=PositionalIndex.build(col.docs[:6], store="vbyte"))
+    assert vb.plan(f"docs: {toks[0]}").strategy == "doc-runs"
 
 
 def test_engine_doclist_paths_agree(col, pidx):
     """Host fast paths and the nonpositional definition give one answer."""
     idx = NonPositionalIndex.build(col.docs, store="repair_skip")
-    eng = QueryEngine(idx, positional=pidx)
-    ponly = QueryEngine(None, positional=pidx)
+    sess = Session(idx, positional=pidx)
+    ponly = Session(None, positional=pidx)
     words = [w for w in idx.vocab.id_to_token[:8]]
     for w in words[:4]:
-        a = eng.doc_list([w])
-        b = ponly.doc_list([w])
+        a = sess.execute(f"docs: {w}")
+        b = ponly.execute(f"docs: {w}")
         c = positions_to_docs(pidx.query_word(w), pidx.doc_starts)
         assert np.array_equal(a, b) and np.array_equal(a, c), w
-    q = [words[0], words[3]]
-    assert np.array_equal(eng.doc_list(q), ponly.doc_list(q))
+    q = f"docs: {words[0]} {words[3]}"
+    assert np.array_equal(sess.execute(q), ponly.execute(q))
 
 
 def test_doc_topk_ranks_by_pattern_frequency(col, pidx):
     idx = NonPositionalIndex.build(col.docs, store="repair_skip")
-    eng = QueryEngine(idx, positional=pidx)
+    sess = Session(idx, positional=pidx)
     tok_lists = [tokenize(d) for d in col.docs]
     w = [t for t in idx.vocab.id_to_token[:6]]
     q = [w[1], w[4]]
-    docs = eng.doc_list(q)
+    docs = sess.execute(f"docs: {q[0]} {q[1]}")
     scores = np.asarray([tok_lists[int(d)].count(q[0]) + tok_lists[int(d)].count(q[1])
                          for d in docs])
     want = docs[np.argsort(-scores, kind="stable")][:3]
-    got = eng.doc_topk(q, k=3)
+    got = sess.execute(f"docs-top3: {q[0]} {q[1]}")
     assert np.array_equal(got, want)
     # phrase frequency ranking
-    ph = tok_lists[0][2:4]
-    got = eng.doc_topk(ph, k=4, phrase=True)
-    pdocs, counts = positions_to_doc_counts(eng.phrase(ph), pidx.doc_starts)
+    ph = " ".join(tok_lists[0][2:4])
+    got = sess.execute(f'docs-top4: "{ph}"')
+    pdocs, counts = positions_to_doc_counts(sess.execute(f'"{ph}"'),
+                                            pidx.doc_starts)
     assert np.array_equal(got, rank_docs(pdocs, counts, 4))
 
 
@@ -183,10 +186,10 @@ def test_doc_topk_ranks_by_pattern_frequency(col, pidx):
 def test_batched_doclist_matches_host(col, store):
     idx = NonPositionalIndex.build(col.docs, store=store)
     pidx2 = PositionalIndex.build(col.docs, store=store)
-    eng = QueryEngine(idx, positional=pidx2,
-                      server=BatchedServer.from_index(idx),
-                      positional_server=BatchedServer.from_index(pidx2))
-    host = QueryEngine(idx, positional=pidx2)
+    sess = Session(idx, positional=pidx2,
+                   server=BatchedServer.from_index(idx),
+                   positional_server=BatchedServer.from_index(pidx2))
+    host = Session(idx, positional=pidx2)
     words = [w for w in idx.vocab.id_to_token[:20]]
     toks = tokenize(col.docs[0])
     queries = [f"docs: {words[1]} {words[4]}",
@@ -194,32 +197,32 @@ def test_batched_doclist_matches_host(col, store):
                f'docs: "{toks[0]}"',
                f'docs: "{toks[1]} {toks[2]}"',
                "docs: zzz-unknown-term"]
-    plans = [eng.planner.plan(q) for q in queries]
+    plans = [sess.plan(q) for q in queries]
     assert [p.route for p in plans[:4]] == ["device"] * 4, plans
-    got = eng.batch(queries)
+    got = sess.execute(queries)
     for q, g in zip(queries, got):
         h = host.execute(q)
         assert np.array_equal(np.asarray(g), np.asarray(h)), (store, q)
 
 
 def test_positional_only_docs_and_stays_on_host(col):
-    """Regression: a positional-only engine with a device server must NOT
+    """Regression: a positional-only session with a device server must NOT
     route non-phrase `docs:` conjunctions to the device — the AND step
     would intersect disjoint *position* lists and return empty; the host
     intersects per-term document runs."""
     pidx2 = PositionalIndex.build(col.docs, store="repair_skip")
-    eng = QueryEngine(None, positional=pidx2,
-                      positional_server=BatchedServer.from_index(pidx2))
+    sess = Session(None, positional=pidx2,
+                   positional_server=BatchedServer.from_index(pidx2))
     toks = tokenize(col.docs[0])
     q = f"docs: {toks[0]} {toks[2]}"
-    pl = eng.planner.plan(q)
+    pl = sess.plan(q)
     assert pl.index == "positional" and pl.route == "host", pl
-    got = eng.batch([q])[0]
-    want = QueryEngine(None, positional=pidx2).doc_list([toks[0], toks[2]])
+    got = sess.execute([q])[0]
+    want = Session(None, positional=pidx2).execute(q)
     assert len(want) > 0 and np.array_equal(np.asarray(got), want)
     # phrase doc listing still takes the device route and agrees
     pq = f'docs: "{toks[0]} {toks[1]}"'
-    assert eng.planner.plan(pq).route == "device"
-    dev = eng.batch([pq])[0]
-    host = QueryEngine(None, positional=pidx2).execute(pq)
+    assert sess.plan(pq).route == "device"
+    dev = sess.execute([pq])[0]
+    host = Session(None, positional=pidx2).execute(pq)
     assert np.array_equal(np.asarray(dev), np.asarray(host))

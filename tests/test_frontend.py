@@ -272,6 +272,28 @@ def test_replica_failover_mid_batch(vbyte_case):
     victim.conjunctive = original
 
 
+def test_replica_failover_mid_rank_batch(vbyte_case):
+    """``rank<k>:`` rides the same dispatch: a replica raising in
+    ``ranked`` fails over and the BM25 answers equal the host's."""
+    idx, _ = vbyte_case
+    words = idx.vocab.id_to_token
+    queries = [f"rank5: {words[i]} {words[i + 1]}" for i in range(1, 5)]
+    rs = ReplicatedServer.build(idx, n_replicas=2)
+    assert "rank" in rs.kinds
+
+    def exploding(queries, k=10, width=None):
+        raise RuntimeError("replica wedged mid-batch")
+
+    rs._replicas[0].server.ranked = exploding
+    session = Session(idx, server=rs)
+    assert all(session.plan(q).route == "device" for q in queries)
+    got = session.execute(queries)
+    for q, ref, res in zip(queries, Session(idx).execute(queries), got):
+        assert np.array_equal(np.asarray(ref), np.asarray(res)), q
+    assert rs.failovers == 1
+    assert rs.replica_status()[1]["served"] == len(queries)
+
+
 def test_all_replicas_failed_is_typed(vbyte_case):
     idx, pidx = vbyte_case
     rs = ReplicatedServer.build(idx, n_replicas=2)

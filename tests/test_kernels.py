@@ -1,6 +1,5 @@
 """Pallas kernels vs pure-jnp oracles — shape/dtype sweeps, interpret mode."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,13 +10,7 @@ from repro.kernels.anchor_intersect.ops import (
     member_batch_tpu,
 )
 from repro.kernels.anchor_intersect.ref import anchor_probe_ref, anchor_probe_sliced_ref
-from repro.kernels.cin_interaction.ops import cin_layer
-from repro.kernels.cin_interaction.ref import cin_layer_ref
 from repro.kernels.dgap_decode.ops import dgap_decode
-from repro.kernels.embedding_bag.ops import embedding_bag
-from repro.kernels.embedding_bag.ref import embedding_bag_ref
-from repro.kernels.flash_attention.ops import flash_attention_tpu
-from repro.models.flash import flash_attention as flash_xla
 
 rng = np.random.default_rng(0)
 
@@ -141,105 +134,3 @@ def test_member_batch_tpu_matches_member_batch():
                            interpret=True)
     assert jnp.array_equal(got, ref)
     assert not bool(np.asarray(got)[ids == 5].any())  # empty list never hits
-
-
-@pytest.mark.parametrize("nb,bs,v,d", [(2, 2, 10, 8), (16, 39, 1000, 10), (8, 5, 128, 130)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_embedding_bag(nb, bs, v, d, dtype):
-    idx = jnp.asarray(rng.integers(0, v, (nb, bs)), jnp.int32)
-    tab = jnp.asarray(rng.normal(size=(v, d)), dtype)
-    got = embedding_bag(idx, tab, bs, interpret=True)
-    ref = embedding_bag_ref(idx.reshape(-1), tab, bs)
-    tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    assert float(jnp.max(jnp.abs(got - ref))) < tol
-
-
-@pytest.mark.parametrize("b,m,hk,h,d", [(4, 6, 8, 5, 10), (16, 39, 200, 200, 10), (3, 4, 4, 7, 130)])
-def test_cin_layer(b, m, hk, h, d):
-    x0 = jnp.asarray(rng.normal(size=(b, m, d)), jnp.float32)
-    xk = jnp.asarray(rng.normal(size=(b, hk, d)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(m * hk, h)), jnp.float32)
-    got = cin_layer(x0, xk, w, interpret=True)
-    ref = cin_layer_ref(x0, xk, w)
-    rel = float(jnp.max(jnp.abs(got - ref)) / (jnp.max(jnp.abs(ref)) + 1e-9))
-    assert rel < 1e-4
-
-
-@pytest.mark.parametrize("b,t,h,kh,hd", [(1, 256, 4, 2, 64), (2, 300, 8, 4, 128), (1, 513, 2, 1, 32)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_kernel_vs_xla(b, t, h, kh, hd, dtype):
-    q = jnp.asarray(rng.normal(size=(b, t, h, hd)), dtype)
-    k = jnp.asarray(rng.normal(size=(b, t, kh, hd)), dtype)
-    v = jnp.asarray(rng.normal(size=(b, t, kh, hd)), dtype)
-    got = flash_attention_tpu(q, k, v, interpret=True)
-    ref = flash_xla(q, k, v, True, 128)
-    tol = 2e-3 if dtype == jnp.float32 else 3e-2
-    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)))) < tol
-
-
-def test_flash_xla_gradients_match_naive():
-    """Custom VJP vs autodiff-through-naive-attention."""
-    b, t, h, kh, hd = 2, 64, 4, 2, 16
-    q = jnp.asarray(rng.normal(size=(b, t, h, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, t, kh, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, t, kh, hd)), jnp.float32)
-
-    def naive(q, k, v):
-        g = h // kh
-        kk = jnp.repeat(k, g, axis=2)
-        vv = jnp.repeat(v, g, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / np.sqrt(hd)
-        mask = jnp.tril(jnp.ones((t, t), bool))
-        s = jnp.where(mask[None, None], s, -jnp.inf)
-        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
-
-    f1 = lambda q, k, v: (flash_xla(q, k, v, True, 16) ** 2).sum()
-    f2 = lambda q, k, v: (naive(q, k, v) ** 2).sum()
-    g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(g1, g2):
-        assert float(jnp.max(jnp.abs(a - b_))) < 1e-4
-
-
-@pytest.mark.parametrize("e,c,d,f", [(2, 8, 16, 16), (4, 100, 64, 200), (3, 256, 512, 512)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_moe_gemm(e, c, d, f, dtype):
-    from repro.kernels.moe_gemm.ops import moe_gemm
-    from repro.kernels.moe_gemm.ref import moe_gemm_ref
-
-    buf = jnp.asarray(rng.normal(size=(e, c, d)), dtype)
-    w = jnp.asarray(rng.normal(size=(e, d, f)), dtype)
-    got = moe_gemm(buf, w, interpret=True)
-    ref = moe_gemm_ref(buf, w)
-    rel = float(jnp.max(jnp.abs(got - ref)) / (jnp.max(jnp.abs(ref)) + 1e-9))
-    assert rel < 1e-3
-
-
-@pytest.mark.parametrize("b,s,h,kh,hd", [(2, 512, 4, 2, 64), (1, 1024, 8, 8, 128), (3, 700, 4, 1, 32)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_decode(b, s, h, kh, hd, dtype):
-    from repro.kernels.flash_decode.ops import flash_decode
-    from repro.models.layers import decode_attention
-
-    q = jnp.asarray(rng.normal(size=(b, 1, h, hd)), dtype)
-    k = jnp.asarray(rng.normal(size=(b, s, kh, hd)), dtype)
-    v = jnp.asarray(rng.normal(size=(b, s, kh, hd)), dtype)
-    pos = jnp.asarray(rng.integers(0, s, b), jnp.int32)
-    got = flash_decode(q, k, v, pos, interpret=True)
-    ref = decode_attention(q, k, v, pos)
-    tol = 2e-5 if dtype == jnp.float32 else 3e-2
-    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)))) < tol
-
-
-def test_flash_decode_position_zero():
-    """Edge: position 0 attends only to the first cache slot."""
-    from repro.kernels.flash_decode.ops import flash_decode
-
-    b, s, h, kh, hd = 1, 512, 2, 1, 32
-    q = jnp.asarray(rng.normal(size=(b, 1, h, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, s, kh, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, kh, hd)), jnp.float32)
-    got = flash_decode(q, k, v, jnp.zeros(b, jnp.int32), interpret=True)
-    # attending to one slot: output == v[0] per head group
-    ref = jnp.broadcast_to(v[:, 0:1, 0][:, :, None, :], (b, 1, h, hd))
-    assert float(jnp.max(jnp.abs(got - ref))) < 1e-5

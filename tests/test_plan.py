@@ -7,19 +7,15 @@ grammar / self-index) over a handcrafted deterministic collection: any
 unintended change to the capability→operator mapping or the cost model
 shows up as a readable diff.  The differential test asserts the acceptance
 criterion: ``Session.execute`` on a shuffled mixed-kind batch returns
-byte-identical answers to per-query ``QueryEngine`` execution across ≥6
+byte-identical answers to host-only per-query execution across ≥6
 backends, and a repeated mixed batch performs **zero re-plans and zero new
 jit traces** on its second submission.
 """
-
-import warnings
 
 import numpy as np
 import pytest
 
 from repro.core.index import NonPositionalIndex, PositionalIndex
-from repro.serving import engine as engine_mod
-from repro.serving.engine import BatchedServer, QueryEngine
 from repro.serving.plan import (
     DocReduce,
     Intersect,
@@ -257,7 +253,7 @@ def test_explain_requires_the_needed_index():
 
 
 # ----------------------------------------------------------------------
-# differential: Session.execute == per-query QueryEngine, ≥6 backends
+# differential: Session.execute == host-only per-query execution, ≥6 backends
 # ----------------------------------------------------------------------
 DIFF_BACKENDS = ("vbyte", "rice_runs", "vbyte_st", "repair_skip",
                  "vbyte_lzend", "rlcsa")
@@ -295,7 +291,7 @@ def test_session_matches_queryengine_per_query(diff_collection, store):
     idx = NonPositionalIndex.build(col.docs, store=store)
     pidx = PositionalIndex.build(col.docs, store=store)
     sess = Session.build(idx, positional=pidx)  # device where applicable
-    ref = QueryEngine(idx, positional=pidx)  # host-only, query by query
+    ref = Session(idx, positional=pidx)  # host-only, query by query
     rng = np.random.default_rng(17)
     batch = _mixed_batch(col, idx, rng)
     got = sess.execute(batch)
@@ -304,7 +300,7 @@ def test_session_matches_queryengine_per_query(diff_collection, store):
         g = np.asarray(g)
         assert g.dtype == want.dtype and np.array_equal(g, want), (
             f"store={store!r} query={q!r} session={g.tolist()} "
-            f"engine={want.tolist()}")
+            f"host={want.tolist()}")
 
 
 # ----------------------------------------------------------------------
@@ -423,58 +419,3 @@ def test_extract_windows_match_stream():
     ex = sess.explain('"grammar index"', extract=1)
     assert "stored-text-slice" in ex
     assert "extract-direct" in si.explain('"grammar index"', extract=1)
-
-
-# ----------------------------------------------------------------------
-# deprecation shims
-# ----------------------------------------------------------------------
-def test_queryengine_per_kind_methods_warn_once():
-    idx = NonPositionalIndex.build(DOCS_FIXTURE, store="vbyte")
-    pidx = PositionalIndex.build(DOCS_FIXTURE, store="vbyte")
-    eng = QueryEngine(idx, positional=pidx)
-    engine_mod._DEPRECATION_WARNED = False
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        eng.word("grammar")
-        eng.conjunctive(["grammar", "query"])
-        eng.phrase(["grammar", "index"])
-        eng.ranked_and(["grammar", "query"], k=2)
-        eng.doc_list(["grammar"])
-        eng.doc_topk(["grammar"], k=2)
-        eng.execute("grammar query")  # not deprecated: no extra warning
-        eng.batch(["grammar"])
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)
-           and "Session" in str(w.message)]
-    assert len(dep) == 1, [str(w.message) for w in caught]
-    # the answers still flow through the Session unchanged
-    sess = Session(idx, positional=pidx)
-    assert np.array_equal(eng.execute("grammar query"),
-                          sess.execute("grammar query"))
-
-
-def test_queryengine_server_attached_after_construction():
-    """Old call sites attach servers post-construction; the shim's owned
-    Session must see them (and drop routes planned without them)."""
-    idx = NonPositionalIndex.build(DOCS_FIXTURE, store="repair_skip")
-    eng = QueryEngine(idx)
-    host = np.asarray(eng.execute("grammar query"))
-    assert eng.planner.plan("grammar query").route == "host"
-    eng.server = BatchedServer.from_index(idx)
-    assert eng.planner.plan("grammar query").route == "device"
-    got = np.asarray(eng.execute("grammar query"))
-    assert eng.session.device_batches > 0, "served on the host despite the server"
-    assert np.array_equal(got, host)
-
-
-def test_queryengine_batch_equals_session_execute():
-    idx = NonPositionalIndex.build(DOCS_FIXTURE, store="repair_skip")
-    pidx = PositionalIndex.build(DOCS_FIXTURE, store="repair_skip")
-    eng = QueryEngine(idx, positional=pidx,
-                      server=BatchedServer.from_index(idx),
-                      positional_server=BatchedServer.from_index(pidx))
-    batch = ["grammar query", '"grammar index"', "top2: grammar query",
-             "docs: grammar query"]
-    got = eng.batch(batch)
-    want = Session(idx, positional=pidx).execute(batch)
-    for q, g, w in zip(batch, got, want):
-        assert np.array_equal(np.asarray(g), np.asarray(w)), q

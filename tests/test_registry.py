@@ -24,7 +24,7 @@ from repro.core.registry import (
 )
 from repro.data import generate_collection
 from repro.data.text import is_word_token, tokenize
-from repro.serving.engine import QueryEngine
+from repro.serving.session import Session
 
 ALL_BACKENDS = backend_names()
 INVERTED = backend_names(family=FAMILY_INVERTED)
@@ -176,21 +176,21 @@ def test_engine_selfindex_matches_inverted(tiny_collection, store):
     """Acceptance: word and phrase queries against self-index backends go
     through the same plan/execute API and equal the inverted answers."""
     docs = tiny_collection.docs
-    ref = QueryEngine(NonPositionalIndex.build(docs, store="repair_skip"),
-                      positional=PositionalIndex.build(docs, store="repair_skip"))
-    eng = QueryEngine(NonPositionalIndex.build(docs, store=store),
-                      positional=PositionalIndex.build(docs, store=store))
-    assert CAP_SHIFTED_INTERSECT in capabilities_of(eng.index.store)
+    ref = Session(NonPositionalIndex.build(docs, store="repair_skip"),
+                  positional=PositionalIndex.build(docs, store="repair_skip"))
+    sess = Session(NonPositionalIndex.build(docs, store=store),
+                   positional=PositionalIndex.build(docs, store=store))
+    assert CAP_SHIFTED_INTERSECT in capabilities_of(sess.index.store)
     words = [w for w in ref.index.vocab.id_to_token[:12]]
     ph = tokenize(docs[0])[2:5]
     queries = [words[1], f"{words[1]} {words[4]}", '"' + " ".join(ph) + '"',
                f"top3: {words[1]} {words[4]}", "xyzzy-not-a-word"]
     for q in queries:
-        plan = eng.planner.plan(q)
+        plan = sess.plan(q)
         assert plan.route == "host"
-        got, want = eng.execute(q), ref.execute(q)
+        got, want = sess.execute(q), ref.execute(q)
         assert np.array_equal(np.sort(np.asarray(got)), np.sort(np.asarray(want))), (store, q)
-    assert eng.planner.plan(f"{words[1]} {words[4]}").strategy == "self-locate"
+    assert sess.plan(f"{words[1]} {words[4]}").strategy == "self-locate"
 
 
 def test_partitioned_from_index_any_backend(tiny_collection):

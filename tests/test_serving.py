@@ -1,4 +1,9 @@
-"""Anchored index + batched serving engine (the uihrdc architecture)."""
+"""Anchored index + the batched AND serve step."""
+
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -6,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.core.anchors import AnchoredIndex, build_anchored, member_batch
-from repro.serving.engine import make_uihrdc_serve_step
+from repro.serving.engine import make_serve_step
+from repro.serving.plan import AND
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +49,7 @@ def test_member_batch_mixed_lists(lists, aidx):
 
 
 def test_serve_step_and_queries(lists, aidx):
-    serve = jax.jit(make_uihrdc_serve_step(max_terms=3))
+    serve = jax.jit(make_serve_step(max_terms=3, mode=AND))
     arrays = {"anchors": aidx.anchors, "c_offsets": aidx.c_offsets,
               "expand": aidx.expand, "expand_valid": aidx.expand_valid,
               "lengths": aidx.lengths}
@@ -99,3 +105,17 @@ def test_partitioned_index_matches_global(lists):
             expect = ref[(ref >= lo) & (ref < hi) & (ref <= cap)]
             shard_got = np.unique(vals[s, qi][mask[s, qi]])
             assert np.array_equal(shard_got, expect), (qi, s)
+
+
+def test_engine_imports_nothing_from_the_session():
+    """The device layer sits below the session: importing it in a fresh
+    interpreter loads no ``repro.serving.session``."""
+    root = Path(__file__).resolve().parents[1]
+    code = ("import sys, repro.serving.engine; "
+            "print('repro.serving.session' in sys.modules)")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, cwd=root, timeout=120,
+                          env={**os.environ, "PYTHONPATH": str(root / "src"),
+                               "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == "False"

@@ -14,13 +14,9 @@ import jax.numpy as jnp
 from repro.core.index import NonPositionalIndex, PositionalIndex
 from repro.data import generate_collection
 from repro.data.text import tokenize
-from repro.serving.engine import (
-    MAX_CAND_ROWS,
-    BatchedServer,
-    QueryEngine,
-    make_serve_step,
-    parse_query,
-)
+from repro.serving.engine import MAX_CAND_ROWS, BatchedServer, make_serve_step
+from repro.serving.plan import parse_query
+from repro.serving.session import Session
 
 STORES = ["repair_skip", "vbyte", "elias_fano"]
 
@@ -100,33 +96,33 @@ def test_phrase_step_covers_long_lists():
 def test_topk_matches_host_ranked_and(col):
     idx = NonPositionalIndex.build(col.docs, store="repair_skip")
     server = BatchedServer.from_index(idx)
-    engine = QueryEngine(idx, server=server)
+    host = Session(idx)
     rng = np.random.default_rng(9)
     words = [w for w in idx.vocab.id_to_token[:150]]
     queries = [[words[int(rng.integers(len(words)))] for _ in range(2)]
                for _ in range(10)]
     dev = server.topk(queries, k=5)
     for q, d in zip(queries, dev):
-        host = engine.ranked_and(q, k=5)
-        assert np.array_equal(np.asarray(d), np.asarray(host)), q
+        want = host.execute(f"top5: {q[0]} {q[1]}")
+        assert np.array_equal(np.asarray(d), np.asarray(want)), q
 
 
 def test_planner_routes_mixed_batch(col):
     idx = NonPositionalIndex.build(col.docs, store="repair_skip")
     pidx = PositionalIndex.build(col.docs, store="repair_skip")
-    engine = QueryEngine(idx, positional=pidx,
-                         server=BatchedServer.from_index(idx),
-                         positional_server=BatchedServer.from_index(pidx))
+    sess = Session(idx, positional=pidx,
+                   server=BatchedServer.from_index(idx),
+                   positional_server=BatchedServer.from_index(pidx))
     toks = tokenize(col.docs[0])[:3]
     w = [t for t in idx.vocab.id_to_token[:10]][:2]
     queries = [w[0], f"{w[0]} {w[1]}", '"' + " ".join(toks) + '"',
                f"top3: {w[0]} {w[1]}"]
-    kinds = [engine.planner.plan(q).query.kind for q in queries]
+    kinds = [parse_query(q).kind for q in queries]
     assert kinds == ["word", "and", "phrase", "topk"]
-    routes = [engine.planner.plan(q).route for q in queries]
+    routes = [sess.plan(q).route for q in queries]
     assert routes[0] == "host" and set(routes[1:]) == {"device"}
-    res = engine.batch(queries)
-    host = QueryEngine(idx, positional=pidx).batch(queries)
+    res = sess.execute(queries)
+    host = Session(idx, positional=pidx).execute(queries)
     for r, h in zip(res, host):
         assert np.array_equal(np.asarray(r), np.asarray(h))
 

@@ -26,7 +26,6 @@ from repro.core.index import NonPositionalIndex, PositionalIndex
 from repro.serving.engine import (
     MAX_CAND_ROWS,
     BatchedServer,
-    QueryEngine,
     make_serve_step,
     window_lanes,
 )
@@ -58,9 +57,9 @@ def boundary_index():
 def test_and_at_window_boundaries(boundary_index, length):
     idx, servers = boundary_index
     wid = idx.word_id(f"w{length}")
-    host = QueryEngine(idx)
+    host = Session(idx)
     q = [f"w{length}", "common"]
-    want = np.asarray(host.conjunctive(q))
+    want = np.asarray(host.execute(f"w{length} common"))
     assert len(want) == length  # w<L> ∩ all-docs == [0, L)
     for layout, server in servers.items():
         c_off = np.asarray(server.arrays["c_offsets"])
@@ -80,9 +79,9 @@ def test_and_at_window_boundaries(boundary_index, length):
 def test_doclist_at_window_boundaries(boundary_index, length):
     """The device doc-listing dedup must also be window-exact."""
     idx, servers = boundary_index
-    host = QueryEngine(idx)
+    host = Session(idx)
     q = [f"w{length}", "common"]
-    want = host.doc_list(q)
+    want = host.execute(f"docs: w{length} common")
     for layout, server in servers.items():
         dev = server.doclist([q])[0]
         assert np.array_equal(dev, want), (layout, length, len(dev))

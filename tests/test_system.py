@@ -1,44 +1,25 @@
 """End-to-end system behaviour: collection -> compressed indexes -> queries
--> serving engine; anchored TPU path == CPU skipping path; configs/dry-run
-plumbing sanity."""
+-> serving session; anchored TPU path == CPU skipping path."""
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from repro.configs import ASSIGNED_ARCHS, all_cells, get_config
 from repro.core.anchors import build_anchored, member_batch
 from repro.core.index import NonPositionalIndex, PositionalIndex
 from repro.data import generate_collection
-from repro.serving.engine import QueryEngine
-
-
-def test_forty_cells_defined():
-    cells = all_cells()
-    assert len(cells) == 40
-    for arch, shape in cells:
-        specs = get_config(arch).input_specs(shape)
-        assert specs, (arch, shape)
-        for k, v in specs.items():
-            assert all(d > 0 for d in v.shape), (arch, shape, k)
-
-
-def test_reduced_configs_exist():
-    for arch in ASSIGNED_ARCHS:
-        r = get_config(arch).reduced()
-        assert r is not None
+from repro.serving.session import Session
 
 
 def test_end_to_end_search(small_collection):
     idx = NonPositionalIndex.build(small_collection.docs, store="repair_skip")
-    engine = QueryEngine(idx)
+    session = Session(idx)
     words = [w for w in idx.vocab.id_to_token[:20]]
-    hits = engine.conjunctive([words[1], words[4]])
+    hits = session.execute(f"{words[1]} {words[4]}")
     # every reported doc really contains both words
     for d in hits.tolist():
         low = small_collection.docs[d].lower()
         assert words[1] in low and words[4] in low
-    ranked = engine.ranked_and([words[1], words[4]], k=3)
+    ranked = session.execute(f"top3: {words[1]} {words[4]}")
     assert len(ranked) <= 3
     assert set(ranked.tolist()) <= set(hits.tolist())
 
